@@ -12,6 +12,8 @@ import sys
 import time
 import traceback
 
+from repro.runtime import use_compile_cache
+
 MODULES = [
     "entropy_integrity",
     "fig1_single_vs_multi",
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated substrings of module names")
     args = ap.parse_args()
+    use_compile_cache()
     selected = MODULES
     if args.only:
         keys = args.only.split(",")

@@ -12,6 +12,7 @@ import numpy as np
 
 from repro.cloudsim import (Catalog, CollectorConfig, DataCollector,
                             SpotMarket, SPSQueryService)
+from repro.runtime import use_compile_cache
 
 
 def collect(mode: str, seed: int, cycles: int, n_targets: int, accounts: int):
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--targets", type=int, default=30)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     print(f"{'mode':<10} {'queries':>8} {'accounts needed':>16} "
           f"{'mean|err|':>10} {'median':>7}")

@@ -22,6 +22,7 @@ from repro.cloudsim import (Catalog, CollectorConfig, DataCollector,
 from repro.core import EngineConfig, ResourceRequest
 from repro.operator import Operator, OperatorConfig
 from repro.stream import LiveIngestor
+from repro.runtime import use_compile_cache
 
 
 def delivered(op: Operator, market: SpotMarket) -> float:
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--period-min", type=float, default=10.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     # 1. the simulated market + collector, warmed to a full window
     market = SpotMarket(Catalog(seed=args.seed, n_regions=2), seed=args.seed)

@@ -8,6 +8,7 @@ import argparse
 from repro.cloudsim import (Catalog, CollectorConfig, DataCollector,
                             SpotMarket, SPSQueryService)
 from repro.core import RecommendationEngine, ResourceRequest
+from repro.runtime import use_compile_cache
 
 
 def main() -> None:
@@ -17,6 +18,7 @@ def main() -> None:
     ap.add_argument("--cycles", type=int, default=30)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     # 1. a (simulated) cloud + the rate-limited SPS query service
     market = SpotMarket(Catalog(seed=args.seed, n_regions=2), seed=args.seed)

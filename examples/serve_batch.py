@@ -17,6 +17,7 @@ from repro.cloudsim import (Catalog, CollectorConfig, DataCollector,
                             SpotMarket, SPSQueryService)
 from repro.core import RecommendationEngine, ResourceRequest
 from repro.serve import BatchServer
+from repro.runtime import use_compile_cache
 
 
 def main() -> None:
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--cycles", type=int, default=30)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
     if args.requests < 1:
         ap.error("--requests must be >= 1")
 

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.models import get_model
+from repro.runtime import use_compile_cache
 
 
 def main() -> None:
@@ -22,6 +23,7 @@ def main() -> None:
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.8)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = get_model(cfg)

@@ -20,6 +20,7 @@ from repro.cloudsim import (Catalog, CollectorConfig, DataCollector,
 from repro.core import RecommendationEngine, ResourceRequest
 from repro.serve import ArchiveCache, BatchServer
 from repro.stream import AdmissionQueue, LiveIngestor
+from repro.runtime import use_compile_cache
 
 
 def main() -> None:
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--requests-per-cycle", type=int, default=6)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     # 1. the live collector (host ring sized to keep column reads O(K))
     market = SpotMarket(Catalog(seed=args.seed, n_regions=2), seed=args.seed)

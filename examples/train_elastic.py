@@ -23,6 +23,7 @@ from repro.configs.registry import get_config
 from repro.data import make_pipeline
 from repro.elastic import ElasticConfig, SpotElasticTrainer
 from repro.models import get_model
+from repro.runtime import use_compile_cache
 
 PRESETS = {
     # reduced same-family config: fast on CPU
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--no-compress", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compile_cache()
 
     p = PRESETS[args.preset]
     cfg = get_config("qwen2-0.5b").reduced(

@@ -314,7 +314,6 @@ class RecommendationEngine:
         if empty.any():
             raise ValueError("no candidates satisfy the request filters "
                              f"(batch row {int(np.flatnonzero(empty)[0])})")
-        impl = pool_lib.resolve_pool_impl(self.pool_impl, len(cands))
         if archive is not None and getattr(archive, "is_sharded", False):
             from .. import shard as shard_lib
             uniq_masks, uniq_inv = _dedup_masks(batch.masks)
@@ -322,10 +321,23 @@ class RecommendationEngine:
                 shard_lib.sharded_batch_arrays(
                     archive, batch.masks, batch.use_cpus, batch.weights,
                     batch.lams, batch.amounts, uniq_masks, uniq_inv,
-                    pool_impl=impl))
+                    pool_impl=pool_lib.resolve_pool_impl(self.pool_impl,
+                                                         len(cands))))
             return self._build_recommendations(
                 cands, batch, requests, comb, avail, cost, order, counts,
                 k_stop, time.perf_counter() - t0)
+        operands, statics = self._fused_operands(cands, batch, archive)
+        comb, avail, cost, order, counts, k_stop, _ = jax.device_get(
+            _fused_recommend_batch(*operands, **statics))
+        return self._build_recommendations(
+            cands, batch, requests, comb, avail, cost, order, counts, k_stop,
+            time.perf_counter() - t0)
+
+    def _fused_operands(self, cands: CandidateSet, batch: RequestBatch,
+                        archive=None):
+        """``(operands, static kwargs)`` of the single-device fused dispatch
+        :meth:`recommend_batch` makes for ``batch``."""
+        impl = pool_lib.resolve_pool_impl(self.pool_impl, len(cands))
         s_impl = scoring.resolve_score_impl(self.score_impl, len(cands))
         if (s_impl == "dense" and archive is not None
                 and not getattr(archive, "dense_capable", True)):
@@ -356,14 +368,10 @@ class RecommendationEngine:
                 jnp.asarray(cands.prices, jnp.float32),
                 jnp.asarray(cands.vcpus, jnp.float32),
                 jnp.asarray(cands.memory_gb, jnp.float32))
-        comb, avail, cost, order, counts, k_stop, _ = jax.device_get(
-            _fused_recommend_batch(
-                t3, prices, vcpus, memory_gb, batch.masks, batch.use_cpus,
-                batch.weights, batch.lams, batch.amounts, stats, uniq_masks,
-                uniq_inv, pool_impl=impl, score_impl=s_impl))
-        return self._build_recommendations(
-            cands, batch, requests, comb, avail, cost, order, counts, k_stop,
-            time.perf_counter() - t0)
+        operands = (t3, prices, vcpus, memory_gb, batch.masks, batch.use_cpus,
+                    batch.weights, batch.lams, batch.amounts, stats,
+                    uniq_masks, uniq_inv)
+        return operands, {"pool_impl": impl, "score_impl": s_impl}
 
     def _build_recommendations(self, cands: CandidateSet, batch: RequestBatch,
                                requests, comb, avail, cost, order, counts,

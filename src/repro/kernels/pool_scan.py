@@ -22,19 +22,23 @@ the identical prefix-sum values with the identical multiply/divide order,
 the pool output is bit-identical to the dense scan by construction — not
 merely up to float reassociation.
 
-Two implementations share that schedule:
+``top[k - 1]`` is the same expression on the previous prefix's sum, which
+the XLA prologue stages as a lagged copy of the prefix sums; so every
+statistic of a lane is elementwise and a tile may have any shape.  Two
+implementations share that tile math (``_tile_stats``):
 
-- ``_pool_scan_lax``    : ``jax.lax.scan`` over (nt, TILE) stat blocks — the
-                          CPU/GPU fallback and the vmap-friendly path the
-                          batched engine uses off-TPU.  The row emission is
-                          a single fused elementwise pass (the winning
-                          prefix sum is a scalar, so no tiling is needed).
-- ``_pool_scan_pallas`` : a Pallas TPU kernel with the same per-tile math,
-                          grid ``(2, nt)`` (phase 0: stats scan, phase 1:
-                          tiled row emission) and the carry in SMEM scratch,
-                          following the ``rwkv6_scan`` grid/scratch idiom.
-                          Validated under ``interpret=True`` on CPU like the
-                          other kernels in this package.
+- ``_pool_scan_lax``    : the whole row as one tile — a fused elementwise
+                          pass plus a min-reduction for the first
+                          terminating index, then the winning row.  The
+                          CPU/GPU path, vmap-friendly for the batched engine.
+- ``_pool_scan_pallas`` : a Pallas TPU kernel, grid ``(2, nt)`` (phase 0:
+                          stats scan, phase 1: tiled row emission), carry in
+                          SMEM scratch.  Each tile is a (tile // 128, 128)
+                          row block of the candidate axis, the layout Mosaic
+                          accepts; "first" is the smallest global index, so
+                          row-major order is candidate order.  Under ``vmap``
+                          the batch becomes the outermost grid axis and the
+                          carry restarts at ``(p, t) == (0, 0)`` of each row.
 
 Both return ``(counts_sorted, k_stop, any_term)`` with semantics identical
 to the dense scan, so ``core.pool`` can switch implementations behind
@@ -61,6 +65,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE = 1024
+#: lane width of a TPU vector register: Pallas blocks are (tile // LANES,
+#: LANES) row-major views of the candidate axis, so every block's last two
+#: dimensions sit on the (8, 128) f32 tiling Mosaic requires.
+LANES = 128
 
 _INT32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -69,6 +77,16 @@ def _clamped_prefix_sums(s: jax.Array) -> jax.Array:
     """Exactly the dense scan's prefix-sum staging (op-for-op)."""
     s_tot = jnp.cumsum(s)
     return jnp.where(s_tot > 0, s_tot, 1.0)
+
+
+def _lagged(csc: jax.Array) -> jax.Array:
+    """``csc[max(k - 1, 0)]``: the prefix sum of the previous prefix.
+
+    It feeds both ``top[k - 1]`` (recomputed elementwise from the same
+    bits, so no cross-lane shift is needed inside a tile) and the winning
+    prefix's sum at ``k_best = max(k_stop - 1, 0)``.
+    """
+    return jnp.concatenate([csc[:1], csc[:-1]])
 
 
 def _pad_tiles(arrs, tile: int, pad_values):
@@ -82,29 +100,49 @@ def _pad_tiles(arrs, tile: int, pad_values):
             for a, v in zip(arrs, pad_values)] + [nt]
 
 
-def _tile_stats(s_t, c_t, csc_t, idx, prev_top, s0, c0, required, k_total):
-    """Termination statistics for one tile of the precomputed prefix sums.
+def _pad_rows(arrs, tile: int, pad_values):
+    """Pallas layout: (K,) arrays padded as :func:`_pad_tiles` and viewed as
+    (nt * tile // LANES, LANES), so tile ``t`` is the row block ``t`` of
+    ``tile // LANES`` rows and row-major order is candidate order."""
+    if tile % LANES:
+        raise ValueError(f"Pallas tile must be a multiple of {LANES}, "
+                         f"got {tile}")
+    *tiles, nt = _pad_tiles(arrs, tile, pad_values)
+    return [a.reshape(nt * tile // LANES, LANES) for a in tiles] + [nt]
+
+
+def _block_index(t, tile: int):
+    """Global candidate index of every lane of row block ``t``."""
+    shape = (tile // LANES, LANES)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return t * tile + row * LANES + lane
+
+
+def _tile_stats(s_t, c_t, csc_t, lag_t, idx, s0, c0, required, k_total):
+    """First terminating prefix index in one tile (``INT32_MAX`` if none).
 
     Float op order matches the dense scan exactly — ``(s * R) / (s_tot * c)``
     on the shared clamped-cumsum values — which is what makes the streamed
-    pool output bit-identical to the dense one.
+    pool output bit-identical to the dense one.  ``top[k - 1]`` is the same
+    expression on ``lag_t`` (the previous prefix's sum), so the statistic
+    needs no lane shift and the tile may have any shape: "first" is the
+    smallest global index ``idx``, i.e. row-major candidate order.
     """
     top = jnp.ceil(s0 * required / (csc_t * c0)).astype(jnp.int32)
+    prev = jnp.ceil(s0 * required / (lag_t * c0)).astype(jnp.int32)
     newest = jnp.ceil(s_t * required / (csc_t * c_t)).astype(jnp.int32)
-    prev = jnp.concatenate([prev_top[None], top[:-1]])
-    term = (top >= prev) | (newest == 0)
-    term = jnp.where(idx == 0, newest == 0, term)         # x_prev_top = inf at k=0
-    term = term & (idx < k_total)                         # padded lanes never vote
-    has = jnp.any(term)
-    local = jnp.argmax(term).astype(jnp.int32)
-    return top, has, local
+    # x_prev_top = inf at k = 0, and padded lanes never vote (plain mask
+    # logic: Mosaic cannot select between boolean vectors)
+    term = ((newest == 0) | ((idx > 0) & (top >= prev))) & (idx < k_total)
+    return jnp.min(jnp.where(term, idx, _INT32_MAX))
 
 
-def _finalize(found, k_stop, k_total):
+def _finalize(first, k_total):
     """Dense-scan semantics for the reduction outputs."""
-    any_term = found
-    k_stop = jnp.where(found, k_stop, 0)                  # argmax of all-False
-    k_best = jnp.where(found, jnp.maximum(k_stop - 1, 0), k_total - 1)
+    any_term = first < _INT32_MAX
+    k_stop = jnp.where(any_term, first, 0)                # argmax of all-False
+    k_best = jnp.where(any_term, jnp.maximum(k_stop - 1, 0), k_total - 1)
     return any_term, k_stop, k_best
 
 
@@ -116,145 +154,98 @@ def _emit_row(s, c, required, stot_best, k_best, deg, c0, lane):
     return jnp.where(deg, jnp.where(lane == 0, fb0, 0), row)
 
 
-def _pool_scan_lax(s: jax.Array, c: jax.Array, required: jax.Array,
-                   *, tile: int = DEFAULT_TILE):
-    """``jax.lax``-tiled fallback: stats scan over (nt, TILE) blocks, then
-    one fused elementwise emission of the winning row."""
+def _pool_scan_lax(s: jax.Array, c: jax.Array, required: jax.Array):
+    """``jax.lax`` path: the tile statistics over the whole row at once (one
+    fused elementwise pass plus a min-reduction), then the winning row."""
     K = s.shape[0]
     csc = _clamped_prefix_sums(s)
     s0, c0 = s[0], c[0]
-    s_tiles, c_tiles, csc_tiles, nt = _pad_tiles(
-        (s, c, csc), tile, (0, 1, 1))
-    idx_tiles = jnp.arange(nt * tile, dtype=jnp.int32).reshape(nt, tile)
-
-    def stats_step(carry, xs):
-        prev_top, found, k_stop = carry
-        s_t, c_t, csc_t, idx = xs
-        top, has, local = _tile_stats(
-            s_t, c_t, csc_t, idx, prev_top, s0, c0, required, K)
-        k_stop = jnp.where(has & ~found, idx[0] + local, k_stop)
-        return (top[-1], found | has, k_stop), None
-
-    init = (jnp.asarray(_INT32_MAX, jnp.int32), jnp.zeros((), bool),
-            jnp.zeros((), jnp.int32))
-    (_, found, k_stop), _ = jax.lax.scan(
-        stats_step, init, (s_tiles, c_tiles, csc_tiles, idx_tiles))
-
-    any_term, k_stop, k_best = _finalize(found, k_stop, K)
-    stot_best = csc[k_best]
-    deg = any_term & (k_stop == 0)
     lane = jnp.arange(K, dtype=jnp.int32)
-    counts = _emit_row(s, c, required, stot_best, k_best, deg, c0, lane)
+    first = _tile_stats(s, c, csc, _lagged(csc), lane, s0, c0, required, K)
+    any_term, k_stop, k_best = _finalize(first, K)
+    deg = any_term & (k_stop == 0)
+    counts = _emit_row(s, c, required, csc[k_best], k_best, deg, c0, lane)
     return counts, k_stop, any_term
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel: same schedule, carry in SMEM scratch.
+# Pallas TPU kernel: same tile math, carry in SMEM scratch.
 # ---------------------------------------------------------------------------
 
-def _pool_scan_kernel(params_ref, s_ref, c_ref, csc_ref, counts_ref, stats_ref,
-                      ptop_scr, found_scr, kstop_scr, stot_scr, cscl_scr,
-                      kbest_scr, deg_scr, *, tile: int, k_total: int, nt: int):
+def _pool_scan_kernel(params_ref, s_ref, c_ref, csc_ref, lag_ref, counts_ref,
+                      stats_ref, first_scr, stot_scr, *, tile: int,
+                      k_total: int, nt: int):
     p = pl.program_id(0)                                  # 0: stats, 1: emit
     t = pl.program_id(1)
     s0 = params_ref[0, 0]
     c0 = params_ref[0, 1]
     required = params_ref[0, 2]
+    idx = _block_index(t, tile)
 
     @pl.when((p == 0) & (t == 0))
     def _init():
-        ptop_scr[0] = jnp.asarray(_INT32_MAX, jnp.int32)
-        found_scr[0] = jnp.int32(0)
-        kstop_scr[0] = jnp.int32(0)
-        stot_scr[0] = jnp.ones((), s_ref.dtype)
-        cscl_scr[0] = jnp.ones((), s_ref.dtype)
-
-    lane = jnp.squeeze(jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1), 0)
+        first_scr[0] = jnp.int32(_INT32_MAX)
+        # not-found: the winning prefix is the full set, csc[K - 1]
+        stot_scr[0] = params_ref[0, 3]
 
     @pl.when(p == 0)
     def _stats():
-        s_t = s_ref[0, :]
-        c_t = c_ref[0, :]
-        csc_t = csc_ref[0, :]
-        idx = t * tile + lane
-        top, has, local = _tile_stats(
-            s_t, c_t, csc_t, idx, ptop_scr[0], s0, c0, required, k_total)
-        cand_kstop = t * tile + local
-        # prefix sum of the last kept prefix k_stop-1: last lane of the
-        # previous tile (the carry) when the hit opens this tile, else the
-        # in-tile value at local-1 (masked reduce: Mosaic has no dynamic
-        # vector indexing).
-        csc_at_lm1 = jnp.sum(
-            jnp.where(lane == jnp.maximum(local - 1, 0), csc_t, 0))
-        cand_stot = jnp.where(
-            cand_kstop == 0, csc_t[0],
-            jnp.where(local == 0, cscl_scr[0], csc_at_lm1))
-        found = found_scr[0]
-        take = has & (found == 0)
-        kstop_scr[0] = jnp.where(take, cand_kstop, kstop_scr[0])
-        stot_scr[0] = jnp.where(take, cand_stot, stot_scr[0])
-        found_scr[0] = jnp.where(has, jnp.int32(1), found)
-        cscl_scr[0] = csc_t[-1]
-        ptop_scr[0] = top[-1]
+        first = _tile_stats(s_ref[...], c_ref[...], csc_ref[...],
+                            lag_ref[...], idx, s0, c0, required, k_total)
+
+        @pl.when((first < _INT32_MAX) & (first_scr[0] == _INT32_MAX))
+        def _take():
+            first_scr[0] = first
+            # csc[k_best] at k_stop = first: the lagged sum on that lane
+            # (masked reduce: Mosaic has no dynamic vector indexing).
+            stot_scr[0] = jnp.sum(jnp.where(idx == first, lag_ref[...], 0.0))
 
     @pl.when((p == 0) & (t == nt - 1))
     def _finish():
-        found = found_scr[0] == 1
-        any_term, k_stop, k_best = _finalize(found, kstop_scr[0], k_total)
-        # not-found: the winning prefix is the full set, csc[K-1] (this tile)
-        last_local = (k_total - 1) - (nt - 1) * tile
-        stot_scr[0] = jnp.where(found, stot_scr[0], csc_ref[0, last_local])
-        kstop_scr[0] = k_stop
-        kbest_scr[0] = k_best
-        deg_scr[0] = (any_term & (k_stop == 0)).astype(jnp.int32)
+        any_term, k_stop, _ = _finalize(first_scr[0], k_total)
         stats_ref[0, 0] = k_stop
         stats_ref[0, 1] = any_term.astype(jnp.int32)
 
     @pl.when(p == 1)
     def _emit():
-        idx = t * tile + lane
-        counts_ref[0, :] = _emit_row(
-            s_ref[0, :], c_ref[0, :], required, stot_scr[0], kbest_scr[0],
-            deg_scr[0] == 1, c0, idx)
+        any_term, k_stop, k_best = _finalize(first_scr[0], k_total)
+        counts_ref[...] = _emit_row(
+            s_ref[...], c_ref[...], required, stot_scr[0], k_best,
+            any_term & (k_stop == 0), c0, idx)
 
 
 def _pool_scan_pallas(s: jax.Array, c: jax.Array, required: jax.Array,
                       *, tile: int = DEFAULT_TILE, interpret: bool = False):
     K = s.shape[0]
     csc = _clamped_prefix_sums(s)        # O(K) XLA op, shared with dense
-    s_tiles, c_tiles, csc_tiles, nt = _pad_tiles(
-        (s, c, csc), tile, (0, 1, 1))
-    params = jnp.stack([s[0], c[0], jnp.asarray(required, s.dtype)]
-                       ).reshape(1, 3)
+    s_r, c_r, csc_r, lag_r, nt = _pad_rows(
+        (s, c, csc, _lagged(csc)), tile, (0, 1, 1, 1))
+    params = jnp.stack([s[0], c[0], jnp.asarray(required, s.dtype),
+                        csc[K - 1]]).reshape(1, 4)
+    rows = tile // LANES
+    block = pl.BlockSpec((rows, LANES), lambda p, t: (t, 0))
     counts, stats = pl.pallas_call(
         functools.partial(_pool_scan_kernel, tile=tile, k_total=K, nt=nt),
         grid=(2, nt),
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda p, t: (0, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, tile), lambda p, t: (t, 0)),
-            pl.BlockSpec((1, tile), lambda p, t: (t, 0)),
-            pl.BlockSpec((1, tile), lambda p, t: (t, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, 4), lambda p, t: (0, 0),
+                               memory_space=pltpu.SMEM)] + [block] * 4,
         out_specs=[
-            pl.BlockSpec((1, tile), lambda p, t: (t, 0)),
-            pl.BlockSpec((1, 2), lambda p, t: (0, 0)),
+            # phase 0 parks on block 0 so nothing unwritten is flushed
+            pl.BlockSpec((rows, LANES), lambda p, t: (t * p, 0)),
+            pl.BlockSpec((1, 2), lambda p, t: (0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nt, tile), jnp.int32),
+            jax.ShapeDtypeStruct((nt * rows, LANES), jnp.int32),
             jax.ShapeDtypeStruct((1, 2), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.SMEM((1,), jnp.int32),    # previous tile's last top[k]
-            pltpu.SMEM((1,), jnp.int32),    # termination found flag
-            pltpu.SMEM((1,), jnp.int32),    # k_stop
+            pltpu.SMEM((1,), jnp.int32),    # first terminating index so far
             pltpu.SMEM((1,), s.dtype),      # prefix sum of winning prefix
-            pltpu.SMEM((1,), s.dtype),      # previous tile's last prefix sum
-            pltpu.SMEM((1,), jnp.int32),    # k_best
-            pltpu.SMEM((1,), jnp.int32),    # degenerate (k_stop == 0) flag
         ],
         interpret=interpret,
-    )(params, s_tiles, c_tiles, csc_tiles)
+        name="pool_scan",
+    )(params, s_r, c_r, csc_r, lag_r)
     return counts.reshape(nt * tile)[:K], stats[0, 0], stats[0, 1].astype(bool)
 
 
@@ -264,8 +255,9 @@ def pool_scan(s: jax.Array, c: jax.Array, required, *, tile: int | None = None,
 
     Drop-in for the dense scan: returns ``(counts_sorted, k_stop, any_term)``
     with identical semantics and bit-identical pool output.  ``backend=None``
-    picks the Pallas kernel on TPU and the ``lax.scan`` tiling elsewhere;
-    ``interpret`` forces the Pallas interpreter (tests).  Traceable under
+    picks the Pallas kernel on TPU and the ``lax`` pass elsewhere;
+    ``interpret`` forces the Pallas interpreter (tests).  ``tile`` is the
+    Pallas block in candidates, a multiple of 128.  Traceable under
     ``jit`` / ``vmap``.
     """
     tile = DEFAULT_TILE if tile is None else tile
@@ -278,4 +270,4 @@ def pool_scan(s: jax.Array, c: jax.Array, required, *, tile: int | None = None,
         return _pool_scan_pallas(s, c, required, tile=tile, interpret=interp)
     if backend != "lax":
         raise ValueError(f"unknown pool_scan backend: {backend!r}")
-    return _pool_scan_lax(s, c, required, tile=tile)
+    return _pool_scan_lax(s, c, required)

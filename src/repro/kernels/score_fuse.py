@@ -21,9 +21,9 @@ two-phase schedule as ``pool_scan``:
                            fallback, vmap-friendly for the batched engine.
 - ``_score_fuse_pallas`` : a Pallas TPU kernel with the same per-tile math,
                            grid ``(2, nt)`` (phase 0: extrema scan, phase 1:
-                           tiled row emission), carry in SMEM scratch —
-                           the ``pool_scan`` / ``rwkv6_scan`` idiom.
-                           Validated under ``interpret=True`` on CPU.
+                           tiled row emission), carry in SMEM scratch, tiles
+                           laid out as (tile // 128, 128) row blocks — the
+                           ``pool_scan`` idiom.
 
 Both share ``_tile_extrema`` / ``_emit_rows``, whose float op order matches
 the dense masked path (``scoring._masked_minmax`` etc.) exactly: min/max
@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pool_scan import _pad_tiles
+from .pool_scan import LANES, _pad_rows, _pad_tiles
 
 DEFAULT_TILE = 1024
 
@@ -83,10 +83,11 @@ def _tile_total(prices_t, vcpus_t, mem_t, use_cpus, required):
 
 
 def _tile_extrema(area_t, slope_t, std_t, mask_t):
-    """Masked per-tile (min, max) of the three availability statistics."""
-    lo = jnp.stack([_masked_min(x, mask_t) for x in (area_t, slope_t, std_t)])
-    hi = jnp.stack([_masked_max(x, mask_t) for x in (area_t, slope_t, std_t)])
-    return lo, hi
+    """Masked per-tile (min, max) of the three availability statistics, as
+    two tuples of scalars (the kernel keeps each in its own SMEM slot)."""
+    stats = (area_t, slope_t, std_t)
+    return (tuple(_masked_min(x, mask_t) for x in stats),
+            tuple(_masked_max(x, mask_t) for x in stats))
 
 
 def _minmax_norm(x, lo, hi):
@@ -136,7 +137,8 @@ def stat_extrema(area: jax.Array, slope: jax.Array, std: jax.Array,
         lo, hi = carry
         a, m, s, k = xs
         t_lo, t_hi = _tile_extrema(a, m, s, k)
-        return (jnp.minimum(lo, t_lo), jnp.maximum(hi, t_hi)), None
+        return (jnp.minimum(lo, jnp.stack(t_lo)),
+                jnp.maximum(hi, jnp.stack(t_hi))), None
 
     init = (jnp.full(3, jnp.inf, jnp.float32),
             jnp.full(3, -jnp.inf, jnp.float32))
@@ -205,13 +207,13 @@ def _score_fuse_kernel(params_ref, a_ref, m_ref, s_ref, p_ref, v_ref, g_ref,
 
     @pl.when(p == 0)
     def _extrema():
-        mask_t = k_ref[0, :] > 0
+        mask_t = k_ref[...] > 0
         if not has_cost_floor:
-            total_t = _tile_total(p_ref[0, :], v_ref[0, :], g_ref[0, :],
+            total_t = _tile_total(p_ref[...], v_ref[...], g_ref[...],
                                   use_cpus, required)
             ext_scr[6] = jnp.minimum(ext_scr[6], _masked_min(total_t, mask_t))
         if not has_extrema:
-            lo, hi = _tile_extrema(a_ref[0, :], m_ref[0, :], s_ref[0, :],
+            lo, hi = _tile_extrema(a_ref[...], m_ref[...], s_ref[...],
                                    mask_t)
             for i in range(3):
                 ext_scr[2 * i] = jnp.minimum(ext_scr[2 * i], lo[i])
@@ -219,15 +221,15 @@ def _score_fuse_kernel(params_ref, a_ref, m_ref, s_ref, p_ref, v_ref, g_ref,
 
     @pl.when(p == 1)
     def _emit():
-        total_t = _tile_total(p_ref[0, :], v_ref[0, :], g_ref[0, :],
+        total_t = _tile_total(p_ref[...], v_ref[...], g_ref[...],
                               use_cpus, required)
         comb, avail, cost = _emit_rows(
-            a_ref[0, :], m_ref[0, :], s_ref[0, :], total_t,
+            a_ref[...], m_ref[...], s_ref[...], total_t,
             ext_scr[0], ext_scr[1], ext_scr[2], ext_scr[3], ext_scr[4],
             ext_scr[5], ext_scr[6], lam, weight)
-        comb_ref[0, :] = comb
-        avail_ref[0, :] = avail
-        cost_ref[0, :] = cost
+        comb_ref[...] = comb
+        avail_ref[...] = avail
+        cost_ref[...] = cost
 
 
 def _score_fuse_pallas(area, slope, std, prices, vcpus, memory_gb, mask,
@@ -235,7 +237,7 @@ def _score_fuse_pallas(area, slope, std, prices, vcpus, memory_gb, mask,
                        cost_floor=None, *, tile: int = DEFAULT_TILE,
                        interpret: bool = False):
     K = area.shape[0]
-    a_t, m_t, s_t, p_t, v_t, g_t, k_t, nt = _pad_tiles(
+    *cols, nt = _pad_rows(
         (area, slope, std, prices, vcpus, memory_gb,
          mask.astype(jnp.float32)), tile, (0, 0, 0, 1, 1, 1, 0))
     inf = jnp.asarray(jnp.inf, jnp.float32)
@@ -249,18 +251,22 @@ def _score_fuse_pallas(area, slope, std, prices, vcpus, memory_gb, mask,
         jnp.asarray(required, jnp.float32), jnp.asarray(lam, jnp.float32),
         jnp.asarray(weight, jnp.float32),
         lo[0], hi[0], lo[1], hi[1], lo[2], hi[2], floor]).reshape(1, 11)
-    row_spec = pl.BlockSpec((1, tile), lambda p, t: (t, 0))
+    rows = tile // LANES
+    in_block = pl.BlockSpec((rows, LANES), lambda p, t: (t, 0))
+    # phase 0 parks the outputs on block 0 so nothing unwritten is flushed
+    out_block = pl.BlockSpec((rows, LANES), lambda p, t: (t * p, 0))
     comb, avail, cost = pl.pallas_call(
         functools.partial(_score_fuse_kernel, has_extrema=extrema is not None,
                           has_cost_floor=cost_floor is not None),
         grid=(2, nt),
         in_specs=[pl.BlockSpec((1, 11), lambda p, t: (0, 0),
-                               memory_space=pltpu.SMEM)] + [row_spec] * 7,
-        out_specs=[row_spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((nt, tile), jnp.float32)] * 3,
+                               memory_space=pltpu.SMEM)] + [in_block] * 7,
+        out_specs=[out_block] * 3,
+        out_shape=[jax.ShapeDtypeStruct((nt * rows, LANES), jnp.float32)] * 3,
         scratch_shapes=[pltpu.SMEM((8,), jnp.float32)],
         interpret=interpret,
-    )(params, a_t, m_t, s_t, p_t, v_t, g_t, k_t)
+        name="score_fuse",
+    )(params, *cols)
     unpad = lambda x: x.reshape(nt * tile)[:K]  # noqa: E731
     return unpad(comb), unpad(avail), unpad(cost)
 

@@ -30,16 +30,15 @@ suites use against ``candidate_stats`` of the materialized window
 (``scoring.stats_from_moments`` is the shared derivation tail).
 
 Everything is elementwise over the candidate axis, so the kernel streams K
-in TILE-sized blocks with the ``_pad_tiles`` discipline of ``pool_scan`` /
-``score_fuse`` but needs no cross-tile carry — the grid is ``(nt,)``, one
-phase, update + derivation fused per tile:
+in the (tile // 128, 128) row blocks of ``pool_scan`` / ``score_fuse`` but
+needs no cross-tile carry — the grid is ``(nt,)``, one phase, update +
+derivation fused per tile:
 
 - ``_stats_update_vec``    : the vectorized jnp fallback (CPU/GPU), a single
                              fused elementwise pass (jit/vmap friendly).
 - ``_stats_update_pallas`` : the Pallas TPU kernel, identical tile math,
                              scalar params (window length, evict flag) in
-                             SMEM.  Validated under ``interpret=True`` on
-                             CPU like the other kernels in this package.
+                             SMEM.
 """
 from __future__ import annotations
 
@@ -53,9 +52,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core import scoring
-from .pool_scan import _pad_tiles
+from .pool_scan import LANES, _pad_rows
 
-DEFAULT_TILE = 1024
+#: 32 rows of 128 lanes: the native (32, 128) tile of the int8 code rows the
+#: quantized tier streams (and four f32 (8, 128) tiles).
+DEFAULT_TILE = 4096
 
 
 class StreamMoments(NamedTuple):
@@ -204,7 +205,7 @@ def _stats_update_kernel(quantized, params_ref, *refs):
     """Shared kernel body; ``quantized`` adds a trailing scale-row input
     feeding the in-register dequantize of the four column operands."""
     n_in = 12 if quantized else 11
-    ins = [r[0, :] for r in refs[:n_in]]
+    ins = [r[...] for r in refs[:n_in]]
     (os0_ref, os0c_ref, os1_ref, os1c_ref, oq_ref, oqc_ref, area_ref,
      slope_ref, std_ref) = refs[n_in:]
     length = params_ref[0, 0]
@@ -212,15 +213,15 @@ def _stats_update_kernel(quantized, params_ref, *refs):
     scale = ins[11] if quantized else None
     (s0, s0c, s1, s1c, q, qc, _), stats = _update_tile(
         *ins[:11], length, evict, scale)
-    os0_ref[0, :] = s0
-    os0c_ref[0, :] = s0c
-    os1_ref[0, :] = s1
-    os1c_ref[0, :] = s1c
-    oq_ref[0, :] = q
-    oqc_ref[0, :] = qc
-    area_ref[0, :] = stats.area
-    slope_ref[0, :] = stats.slope
-    std_ref[0, :] = stats.std
+    os0_ref[...] = s0
+    os0c_ref[...] = s0c
+    os1_ref[...] = s1
+    os1c_ref[...] = s1c
+    oq_ref[...] = q
+    oqc_ref[...] = qc
+    area_ref[...] = stats.area
+    slope_ref[...] = stats.slope
+    std_ref[...] = stats.std
 
 
 def _stats_update_pallas(moments: StreamMoments, y_new, y_old, y_first,
@@ -230,12 +231,12 @@ def _stats_update_pallas(moments: StreamMoments, y_new, y_old, y_first,
     quantized = scale is not None
     arrs = (*moments, y_new, y_old, y_first, y_last) \
         + ((scale,) if quantized else ())
-    tiles = _pad_tiles(arrs, tile, (0,) * len(arrs))
-    nt = tiles.pop()
+    *tiles, nt = _pad_rows(arrs, tile, (0,) * len(arrs))
     params = jnp.stack([jnp.asarray(length, jnp.float32),
                         jnp.where(evict, 1.0, 0.0).astype(jnp.float32)]
                        ).reshape(1, 2)
-    row_spec = pl.BlockSpec((1, tile), lambda t: (t, 0))
+    rows = tile // LANES
+    row_spec = pl.BlockSpec((rows, LANES), lambda t: (t, 0))
     out = pl.pallas_call(
         functools.partial(_stats_update_kernel, quantized),
         grid=(nt,),
@@ -243,8 +244,9 @@ def _stats_update_pallas(moments: StreamMoments, y_new, y_old, y_first,
                                memory_space=pltpu.SMEM)]
         + [row_spec] * len(arrs),
         out_specs=[row_spec] * 9,
-        out_shape=[jax.ShapeDtypeStruct((nt, tile), jnp.float32)] * 9,
+        out_shape=[jax.ShapeDtypeStruct((nt * rows, LANES), jnp.float32)] * 9,
         interpret=interpret,
+        name="stats_update",
     )(params, *tiles)
     unpad = lambda x: x.reshape(nt * tile)[:K]  # noqa: E731
     out = [unpad(x) for x in out]

@@ -2,24 +2,19 @@
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """`jax.make_mesh` across jax versions.
-
-    Newer jax wants explicit ``axis_types`` (Auto) for GSPMD meshes; older
-    releases (<= 0.4.x) predate ``jax.sharding.AxisType`` and default to Auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def auto_mesh(shape, axes):
+    """A GSPMD mesh: ``jax.make_mesh`` with every axis ``Auto`` (its default
+    is ``Explicit``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return auto_mesh((1, 1), ("data", "model"))

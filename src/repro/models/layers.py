@@ -9,11 +9,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .param import ParamSpec
 
-try:                                    # jax >= 0.6 exports it at top level
-    shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
-
 
 def constrain(x: jax.Array, cfg, template: tuple) -> jax.Array:
     """Activation sharding constraint from a template of {"dp","model","sp",None}.
@@ -97,8 +92,8 @@ def tp_project_rs(h: jax.Array, w: jax.Array, cfg, *, contract_model_dims: int):
         y = jnp.einsum(ein, hl, wl)           # local partial sum
         return jax.lax.psum_scatter(y, "model", scatter_dimension=1, tiled=True)
 
-    return shard_map(local, mesh=mesh, in_specs=(h_spec, w_spec),
-                     out_specs=out_spec)(h, w)
+    return jax.shard_map(local, mesh=mesh, in_specs=(h_spec, w_spec),
+                         out_specs=out_spec)(h, w)
 
 
 def rmsnorm(scale: jax.Array, x: jax.Array, eps: float = 1e-6) -> jax.Array:

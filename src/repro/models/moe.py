@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .layers import constrain, shard_map
+from .layers import constrain
 from .param import ParamSpec
 
 
@@ -202,9 +202,9 @@ def _apply_moe_shardmap(cfg: ModelConfig, p: dict, x: jax.Array):
             aux = jax.lax.pmean(aux, dp)
         return y.reshape(Bl, Sl, D), aux
 
-    fn = shard_map(local_moe, mesh=mesh,
-                   in_specs=(p_specs, x_spec),
-                   out_specs=(x_spec, P()))
+    fn = jax.shard_map(local_moe, mesh=mesh,
+                       in_specs=(p_specs, x_spec),
+                       out_specs=(x_spec, P()))
     return fn(p, x)
 
 

@@ -287,6 +287,24 @@ class RollingDeviceArchive:
         :attr:`version`, and drops the memoised logical window.  Returns
         ``self`` for chaining.
         """
+        step, operands, statics = self._append_dispatch(column)
+        if self.precision == "float32":
+            self._buf, self._moments, stats = step(*operands, **statics)
+        else:
+            self._buf, self._moments, stats, self._clips = step(
+                *operands, **statics)
+        self._pos = (self._pos + 1) % self.capacity
+        self._len = min(self._len + 1, self.capacity)
+        self._stats = stats
+        self._t3_logical = None
+        self.version += 1
+        self.appends += 1
+        return self
+
+    def _append_dispatch(self, column):
+        """``(jitted tick step, operands, static kwargs)`` that
+        :meth:`append` runs to absorb ``column``.  Reads the evicted column
+        (its own dispatch) but changes nothing."""
         col = jnp.asarray(np.asarray(column, np.float32), jnp.float32)
         if col.shape != (len(self.host),):
             raise ValueError(
@@ -297,24 +315,14 @@ class RollingDeviceArchive:
         new_start = (slot + 1) % self.capacity if evict else \
             (slot + 1 - new_len) % self.capacity
         y_old = _read_col(self._buf, jnp.int32(slot))
-        if self.precision == "float32":
-            self._buf, self._moments, stats = _append_step(
-                self._buf, self._moments, col, y_old, jnp.int32(slot),
-                jnp.int32(new_start), jnp.float32(new_len),
+        tail = (jnp.int32(slot), jnp.int32(new_start), jnp.float32(new_len),
                 jnp.asarray(evict, bool))
-        else:
-            self._buf, self._moments, stats, self._clips = _append_step_q(
-                self._buf, self._moments, self._clips, col, y_old,
-                self.scale, jnp.int32(slot), jnp.int32(new_start),
-                jnp.float32(new_len), jnp.asarray(evict, bool),
-                precision=self.precision)
-        self._pos = (slot + 1) % self.capacity
-        self._len = new_len
-        self._stats = stats
-        self._t3_logical = None
-        self.version += 1
-        self.appends += 1
-        return self
+        if self.precision == "float32":
+            return (_append_step,
+                    (self._buf, self._moments, col, y_old, *tail), {})
+        return (_append_step_q,
+                (self._buf, self._moments, self._clips, col, y_old,
+                 self.scale, *tail), {"precision": self.precision})
 
     def snapshot(self) -> ArchiveSnapshot:
         """Pin the current version for an in-flight batch (tiled stage)."""

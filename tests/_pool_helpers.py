@@ -12,6 +12,9 @@ from repro.core import pool as pool_lib
 
 TILE = 16          # small test tile: the fixed lane width spans several tiles
 KW = 3 * TILE      # fixed width -> one compiled shape for every example
+# Pallas blocks are (tile // 128, 128) row views, so the smallest Pallas
+# tile is one row of 128 lanes.
+PALLAS_TILE = 128
 
 
 @functools.partial(jax.jit, static_argnames=("impl", "tile"))

@@ -9,6 +9,9 @@ from repro.core import scoring
 
 TILE = 16          # small test tile: the fixed lane width spans several tiles
 KW = 3 * TILE      # fixed width -> one compiled shape for every example
+# Pallas blocks are (tile // 128, 128) row views, so the smallest Pallas
+# tile is one row of 128 lanes.
+PALLAS_TILE = 128
 
 # scores live at O(100); a float32 ulp there is ~7.6e-6.  Allow a few ulp of
 # shape-dependent FMA contraction, same budget as tests/test_serve_batch.py.

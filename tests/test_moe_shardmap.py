@@ -21,8 +21,8 @@ SCRIPT = textwrap.dedent("""
     from repro.models import moe as moe_lib
     from repro.models.param import init_params
 
-    from repro.launch.mesh import compat_make_mesh
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import auto_mesh
+    mesh = auto_mesh((2, 4), ("data", "model"))
     base = ModelConfig(
         arch_id="t", family="moe", num_layers=1, d_model=32, num_heads=4,
         num_kv_heads=4, head_dim=8, d_ff=64, vocab_size=128,

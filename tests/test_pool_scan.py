@@ -20,8 +20,8 @@ import jax.numpy as jnp
 from repro.core import pool as pool_lib
 from repro.kernels import pool_scan as pool_scan_lib
 
-from _pool_helpers import (KW, TILE, adversarial_instance, as_jax,
-                           masked_pool)
+from _pool_helpers import (KW, PALLAS_TILE, TILE, adversarial_instance,
+                           as_jax, masked_pool)
 
 
 def test_all_masked_row_matches_dense():
@@ -83,24 +83,56 @@ def test_resolve_pool_impl():
 # Pallas kernel (interpret mode) against the dense scan
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("k,seed", [(7, 0), (TILE, 1), (TILE + 5, 2),
-                                    (2 * TILE, 3)])
-def test_pallas_interpret_matches_dense(k, seed):
+def _sorted_instance(k, seed):
     rng = np.random.default_rng(seed)
     s = np.sort(rng.uniform(0.0, 50.0, k))[::-1].copy()
     if k > 4:
         s[-2:] = 0.0                           # zero tail after sorting
     c = rng.choice([2, 4, 8, 16], k).astype(float)
     req = float(rng.integers(16, 2000)) / 4
-    sj = jnp.asarray(s, jnp.float32)
-    cj = jnp.asarray(c, jnp.float32)
+    return jnp.asarray(s, jnp.float32), jnp.asarray(c, jnp.float32), req
+
+
+# K = 8 * PALLAS_TILE + 3 is not a multiple of the block: the last row block
+# is mostly padding, and with 9 blocks the hit can land anywhere.
+@pytest.mark.parametrize("k,seed", [(7, 0), (PALLAS_TILE, 1),
+                                    (PALLAS_TILE + 5, 2), (2 * PALLAS_TILE, 3),
+                                    (8 * PALLAS_TILE + 3, 4)])
+def test_pallas_interpret_matches_dense(k, seed):
+    sj, cj, req = _sorted_instance(k, seed)
     dense = jax.device_get(pool_lib._prefix_allocations(
         sj, cj, jnp.float32(req)))
     pallas = jax.device_get(pool_scan_lib._pool_scan_pallas(
-        sj, cj, jnp.float32(req), tile=TILE, interpret=True))
+        sj, cj, jnp.float32(req), tile=PALLAS_TILE, interpret=True))
     np.testing.assert_array_equal(dense[0], pallas[0])
     assert int(dense[1]) == int(pallas[1])
     assert bool(dense[2]) == bool(pallas[2])
+
+
+def test_pallas_interpret_vmapped_resets_carry_per_row():
+    """Batching the kernel adds a grid axis; the SMEM carry (first
+    terminating index, winning prefix sum) must restart on every row.  Row 1
+    never terminates and row 2 terminates late, after row 0 terminated early:
+    a carry leaking across rows would hand rows 1 and 2 row 0's hit."""
+    k = 3 * PALLAS_TILE + 11
+    rows = [_sorted_instance(k, seed) for seed in (5, 6, 7)]
+    s = jnp.stack([r[0] for r in rows])
+    c = jnp.stack([r[1] for r in rows])
+    req = jnp.asarray([4.0, 1e9, 3000.0], jnp.float32)
+    s = s.at[1].set(jnp.linspace(50.0, 1.0, k, dtype=jnp.float32))
+    c = c.at[1].set(1.0)
+    fn = functools.partial(pool_scan_lib._pool_scan_pallas, tile=PALLAS_TILE,
+                           interpret=True)
+    batched = jax.device_get(jax.vmap(fn)(s, c, req))
+    stops = []
+    for b in range(3):
+        dense = jax.device_get(pool_lib._prefix_allocations(s[b], c[b],
+                                                            req[b]))
+        for x, y in zip(batched, dense):
+            np.testing.assert_array_equal(np.asarray(x)[b], y)
+        stops.append((int(dense[1]), bool(dense[2])))
+    assert not stops[1][1] and stops[0][1]
+    assert stops[2][0] > stops[0][0]
 
 
 # ---------------------------------------------------------------------------
@@ -108,7 +140,6 @@ def test_pallas_interpret_matches_dense(k, seed):
 # ---------------------------------------------------------------------------
 
 def test_vectorized_honors_x64(monkeypatch):
-    from jax.experimental import enable_x64
     seen = {}
     orig = pool_lib._greedy_pool_core
 
@@ -119,7 +150,7 @@ def test_vectorized_honors_x64(monkeypatch):
     monkeypatch.setattr(pool_lib, "_greedy_pool_core", spy)
     scores, cpus = np.array([30.0, 20.0, 10.0]), np.array([4.0, 8.0, 16.0])
     oracle = pool_lib.greedy_pool(scores, cpus, 64.0)
-    with enable_x64():
+    with jax.enable_x64():
         for impl in ("dense", "tiled"):    # both scans must run in float64
             res = pool_lib.greedy_pool_vectorized(scores, cpus, 64.0,
                                                   impl=impl)

@@ -26,8 +26,8 @@ from repro.core import scoring
 from repro.core.types import CandidateSet, ResourceRequest
 from repro.kernels import score_fuse as sf
 
-from _score_helpers import (ATOL, KW, RTOL, TILE, assert_matches_oracle,
-                            instance, kernel_args)
+from _score_helpers import (ATOL, KW, PALLAS_TILE, RTOL, TILE,
+                            assert_matches_oracle, instance, kernel_args)
 
 
 @pytest.mark.parametrize("k", [1, 2, TILE - 1, TILE, TILE + 1, 2 * TILE, KW])
@@ -95,10 +95,11 @@ def test_extrema_short_circuit_is_bitwise():
     mask[0] = True
     args = kernel_args(t3, prices, vcpus, mems, mask, True, 200.0, 0.15, 0.4)
     lo, hi = sf.stat_extrema(args[0], args[1], args[2], args[6], tile=TILE)
-    for backend, interpret in (("lax", None), ("pallas", True)):
-        full = sf.score_fuse(*args, tile=TILE, backend=backend,
+    for backend, interpret, tile in (("lax", None, TILE),
+                                     ("pallas", True, PALLAS_TILE)):
+        full = sf.score_fuse(*args, tile=tile, backend=backend,
                              interpret=interpret)
-        short = sf.score_fuse(*args, extrema=(lo, hi), tile=TILE,
+        short = sf.score_fuse(*args, extrema=(lo, hi), tile=tile,
                               backend=backend, interpret=interpret)
         for a, b in zip(full, short):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -124,17 +125,20 @@ def test_cost_floor_short_circuit_is_bitwise():
                                args[6][cut:], True, 200.0)))
     np.testing.assert_array_equal(np.asarray(floor), merged)
     lo, hi = sf.stat_extrema(args[0], args[1], args[2], args[6], tile=TILE)
-    for backend, interpret in (("lax", None), ("pallas", True)):
-        full = sf.score_fuse(*args, tile=TILE, backend=backend,
+    for backend, interpret, tile in (("lax", None, TILE),
+                                     ("pallas", True, PALLAS_TILE)):
+        full = sf.score_fuse(*args, tile=tile, backend=backend,
                              interpret=interpret)
         short = sf.score_fuse(*args, extrema=(lo, hi), cost_floor=floor,
-                              tile=TILE, backend=backend, interpret=interpret)
+                              tile=tile, backend=backend, interpret=interpret)
         for a, b in zip(full, short):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("k,seed", [(7, 0), (TILE, 1), (TILE + 5, 2),
-                                    (2 * TILE, 3)])
+# K = 8 * PALLAS_TILE + 3: not a multiple of the block, 9 row blocks.
+@pytest.mark.parametrize("k,seed", [(7, 0), (PALLAS_TILE, 1),
+                                    (PALLAS_TILE + 5, 2), (2 * PALLAS_TILE, 3),
+                                    (8 * PALLAS_TILE + 3, 4)])
 def test_pallas_interpret_matches_lax(k, seed):
     rng = np.random.default_rng(seed)
     t3, prices, vcpus, mems = instance(seed, k)
@@ -143,7 +147,7 @@ def test_pallas_interpret_matches_lax(k, seed):
     args = kernel_args(t3, prices, vcpus, mems, mask, bool(seed % 2),
                        96.0, 0.1, 0.5)
     lax_out = sf.score_fuse(*args, tile=TILE, backend="lax")
-    pal_out = sf.score_fuse(*args, tile=TILE, backend="pallas",
+    pal_out = sf.score_fuse(*args, tile=PALLAS_TILE, backend="pallas",
                             interpret=True)
     for a, b in zip(lax_out, pal_out):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -182,14 +186,42 @@ def test_vmapped_matches_per_lane():
                                        rtol=RTOL, atol=ATOL)
 
 
+def test_pallas_interpret_vmapped_resets_carry_per_row():
+    """Batching the kernel adds a grid axis; the phase-0 SMEM carry (three
+    stat extrema + C_min) must restart on every row.  Row 0 sees every
+    candidate, rows 1-2 narrow masks: extrema or a C_min leaking from row 0
+    would renormalise rows 1-2 far outside the ulp budget."""
+    k = 3 * PALLAS_TILE + 11
+    rng = np.random.default_rng(12)
+    t3, prices, vcpus, mems = instance(12, k)
+    masks = np.ones((3, k), bool)
+    masks[1] = rng.random(k) < 0.2
+    masks[2, : k // 2] = False
+    masks[1, 5] = True
+    ucs = np.array([True, False, True])
+    reqs = np.array([96.0, 640.0, 32.0], np.float32)
+    area, slope, std = scoring.candidate_stats(jnp.asarray(t3))
+    shared = (jnp.asarray(prices, jnp.float32),
+              jnp.asarray(vcpus, jnp.float32), jnp.asarray(mems, jnp.float32))
+    fn = functools.partial(sf.score_fuse, tile=PALLAS_TILE, backend="pallas",
+                           interpret=True)
+    batched = jax.vmap(
+        lambda m, uc, r: fn(area, slope, std, *shared, m, uc, r,
+                            jnp.float32(0.1), jnp.float32(0.5))
+    )(jnp.asarray(masks), jnp.asarray(ucs), jnp.asarray(reqs))
+    for b in range(3):
+        assert_matches_oracle([np.asarray(x)[b] for x in batched], t3,
+                              prices, vcpus, mems, masks[b], bool(ucs[b]),
+                              float(reqs[b]), 0.1, 0.5)
+
+
 def test_x64_pins_float32():
     """Like the dense scoring path, the kernel stays float32 under x64."""
-    from jax.experimental import enable_x64
     t3, prices, vcpus, mems = instance(10)
     mask = np.ones(KW, bool)
     args = (t3, prices, vcpus, mems, mask, True, 64.0, 0.1, 0.5)
     base = sf.score_fuse(*kernel_args(*args), tile=TILE, backend="lax")
-    with enable_x64():
+    with jax.enable_x64():
         x64 = sf.score_fuse(*kernel_args(*args), tile=TILE, backend="lax")
     for a, b in zip(base, x64):
         assert np.asarray(b).dtype == np.float32

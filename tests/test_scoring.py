@@ -3,7 +3,7 @@ import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="install the [test] extra for property tests")
-from hypothesis import given, settings, strategies as st  # noqa: E402
+from hypothesis import example, given, settings, strategies as st  # noqa: E402
 import hypothesis.extra.numpy as hnp  # noqa: E402
 
 from repro.core import scoring
@@ -59,14 +59,65 @@ def test_combined_weight_extremes():
     assert np.allclose(scoring.combined_scores(av, co, 1.0), av)
 
 
+def _minmax_atol(t3, lam=scoring.DEFAULT_LAMBDA, base=2e-3):
+    """Absolute score tolerance of the float32 path against the float64
+    reference.
+
+    Eq. 3 MinMax-normalises each statistic across candidates, mapping any
+    non-zero range onto [0, 1].  Where a statistic's range is no larger
+    than its rounding error, the normalised component is rounding noise
+    blown up to full scale, in either precision: the float32 path rounds
+    its inputs (and flushes those below the float32 normal range to 0),
+    the float64 reference normalises its own ulp-level noise (two flat rows
+    have slopes that differ by ~1e-17).  The std squares its deviations,
+    which underflow in float32 below the square root of its smallest normal
+    number.  So each component may be off by its statistic's float32
+    rounding error over the statistic's range, capped at the whole [0, 1],
+    and the score by those errors weighted as Eq. 3 weighs them.  Where that
+    bound is below ``base`` (well-conditioned archives) the tolerance stays
+    ``base``.
+    """
+    t3 = np.asarray(t3, np.float64)
+    T = t3.shape[-1]
+    f32 = np.finfo(np.float32)
+    # worst-case float32 error of a T-term sum of samples up to max|t3|
+    unit = T * (f32.eps * np.abs(t3).max() + np.sqrt(f32.tiny))
+    t = np.arange(T) - (T - 1) / 2.0
+    stats = (np.trapezoid(t3, axis=-1),
+             (t3 - t3.mean(-1, keepdims=True)) @ t / max(t @ t, 1.0),
+             t3.std(-1))
+    frac = [min(1.0, err / r) if r > err else 1.0
+            for err, r in zip((T * unit, unit, unit),
+                              (np.ptp(x) for x in stats))]
+    return max(base,
+               100.0 * ((1 + lam) * frac[0] + lam * (frac[1] + frac[2])))
+
+
 @settings(max_examples=50, deadline=None)
 @given(hnp.arrays(np.float64, hnp.array_shapes(min_dims=2, max_dims=2,
                                                min_side=2, max_side=16),
                   elements=st.floats(0, 50)))
+# underflow: every sample is below the float32 range, so the device holds
+# an all-zero (flat) archive while the reference normalises the tiny values
+@example(np.array([[0.0, 7.44872264e-203], [7.44872264e-203, 7.44872264e-203]]))
+# flat rows: the reference's float64 slope/std noise gets MinMax-normalised
+@example(np.array([[0.7] * 7, [1.1] * 7]))
 def test_jax_matches_numpy_reference(t3):
     got = np.asarray(scoring.availability_scores(t3))
     want = scoring.availability_scores_ref(t3)
-    np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+    np.testing.assert_allclose(got, want, rtol=2e-3, atol=_minmax_atol(t3))
+
+
+def test_minmax_atol_is_tight_on_resolved_ranges():
+    """The conditioning term vanishes where every range is resolved: a
+    well-spread archive of the property's size is held to the base
+    tolerance."""
+    rng = np.random.default_rng(4)
+    t3 = rng.uniform(0.0, 50.0, (16, 16))
+    assert _minmax_atol(t3) < 2.1e-3
+    np.testing.assert_allclose(scoring.availability_scores(t3),
+                               scoring.availability_scores_ref(t3),
+                               rtol=2e-3, atol=2e-3)
 
 
 @settings(max_examples=50, deadline=None)

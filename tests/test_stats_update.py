@@ -87,7 +87,7 @@ def test_long_stream_no_drift():
 
 @pytest.mark.parametrize("backend,ticks,kwargs", [
     ("vec", 2000, {}),
-    ("pallas", 50, {"interpret": True, "tile": 64}),
+    ("pallas", 50, {"interpret": True, "tile": 128}),
 ])
 def test_quantized_long_stream_no_drift(backend, ticks, kwargs):
     """Quantized tier over a long sliding stream: the fused
@@ -155,7 +155,9 @@ def test_flat_rows_keep_exact_zero_std():
         np.testing.assert_array_equal(np.asarray(stats.slope), np.zeros(K))
 
 
-@pytest.mark.parametrize("K", [5, 96, 100])
+# Pallas blocks are (tile // 128, 128) rows: with tile=128, K=256 is two
+# whole blocks and K=300 three with a ragged last one.
+@pytest.mark.parametrize("K", [5, 256, 300])
 def test_pallas_interpret_matches_vec(K):
     rng = np.random.default_rng(K + 1)
     T = 17
@@ -166,7 +168,7 @@ def test_pallas_interpret_matches_vec(K):
     args = (m, col, win[:, 0], slid[:, 0], slid[:, -1], T, True)
     mv, sv = su.stats_update(*args, backend="vec")
     mp, sp = su.stats_update(*args, backend="pallas", interpret=True,
-                             tile=32)
+                             tile=128)
     # primary sums are bitwise; compensations differ by FMA contraction only
     for a, b in ((mv.s0, mp.s0), (mv.s1, mp.s1), (mv.q, mp.q)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
