@@ -128,9 +128,11 @@ def fused_rows(engine, cands, reqs, archive):
     from repro.core import engine as engine_lib
     from repro.core.types import RequestBatch
     batch = RequestBatch.from_requests(cands, reqs, pad_to=B)
-    ops, statics = engine._fused_operands(cands, batch, archive)
+    statics = engine._fused_statics(cands, archive)
     require(statics == {"pool_impl": "tiled", "score_impl": "tiled"},
             f"the engine did not pick the tiled paths: {statics}")
+    ops = engine._fused_operands(cands, batch,
+                                 engine_lib._dedup_masks(batch.masks), archive)
     fn = engine_lib._fused_recommend_batch
     return batch, jax.device_get(fn(*ops, **statics)), fn.lower(
         *ops, **statics)

@@ -250,10 +250,11 @@ def _scalar_fields(obj) -> tuple:
 
 
 def guard_stats(registry: LockRegistry, stats, locks, *,
-                label: str | None = None, histogram_attrs=("latency",)):
+                label: str | None = None, histogram_attrs=()):
     """Guard every scalar counter of a stats dataclass, plus the scalar
-    counters of any attached latency histograms (which inherit the owner's
-    lock discipline by design — see ``serve/histogram.py``)."""
+    counters of the latency histograms named in ``histogram_attrs`` (which
+    inherit the owner's lock discipline by design — see
+    ``serve/histogram.py``)."""
     registry.guard(stats, fields=_scalar_fields(stats), locks=locks,
                    label=label or type(stats).__name__)
     for attr in histogram_attrs:

@@ -25,7 +25,6 @@ Two entry points:
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +32,7 @@ import numpy as np
 
 from . import pool as pool_lib
 from . import scoring
+from .. import tracing
 from ..kernels import score_fuse as score_fuse_lib
 from .config import EngineConfig, resolve_engine_config
 from .types import CandidateSet, Recommendation, RequestBatch, ResourceRequest
@@ -52,19 +52,21 @@ def _dedup_masks(masks: np.ndarray):
     row 0, computed-and-ignored) so the set of compiled (U, K) shapes stays
     bounded at log2(B) per batch shape.
     """
-    packed = np.packbits(masks, axis=1)
-    index: dict = {}
-    rows: list[int] = []
-    inv = np.empty(masks.shape[0], np.int32)
-    for b in range(masks.shape[0]):
-        key = packed[b].tobytes()
-        i = index.setdefault(key, len(rows))
-        if i == len(rows):
-            rows.append(b)
-        inv[b] = i
-    u_pad = 1 << (len(rows) - 1).bit_length()
-    rows = rows + [rows[0]] * (u_pad - len(rows))
-    return masks[np.asarray(rows)], inv
+    with tracing.span("repro.engine.dedup") as span:
+        packed = np.packbits(masks, axis=1)
+        index: dict = {}
+        rows: list[int] = []
+        inv = np.empty(masks.shape[0], np.int32)
+        for b in range(masks.shape[0]):
+            key = packed[b].tobytes()
+            i = index.setdefault(key, len(rows))
+            if i == len(rows):
+                rows.append(b)
+            inv[b] = i
+        u_pad = 1 << (len(rows) - 1).bit_length()
+        span.set_metadata(unique=len(rows), padded=u_pad)
+        rows = rows + [rows[0]] * (u_pad - len(rows))
+        return masks[np.asarray(rows)], inv
 
 
 @functools.partial(jax.jit, static_argnames=("score_impl",))
@@ -253,7 +255,8 @@ class RecommendationEngine:
 
     def recommend_batch(self, cands: CandidateSet, requests,
                         *, pad_to: int | None = None,
-                        archive=None) -> list[Recommendation]:
+                        archive=None,
+                        on_transfer=None) -> list[Recommendation]:
         """Serve B requests in one fused dispatch; order matches ``requests``.
 
         Parity with calling :meth:`recommend` per request: the recommended
@@ -274,11 +277,7 @@ class RecommendationEngine:
         out.  Both entry points therefore agree: there is no empty-pool
         ``Recommendation``, only the raise.
 
-        Diagnostics: ``solve_time_s`` is the **whole-batch wall time** —
-        batch assembly through device read-back — stamped identically on
-        every request in the batch.  It is a batch-throughput figure, not a
-        per-request latency; divide by ``diagnostics["batch_size"]`` for a
-        per-request amortized cost.
+        Diagnostics: ``batch_size`` is the padded batch the request rode in.
 
         ``pad_to`` pads the batch axis so the serve layer can bound the set
         of compiled (B, K) shapes; padded rows are computed-and-discarded.
@@ -289,6 +288,11 @@ class RecommendationEngine:
         archive (``repro.shard``, ``is_sharded = True``) routes to the
         per-shard pipeline instead of the single-device fused dispatch; its
         pools are bit-identical to the single-device tiled path.
+
+        ``on_transfer(d2h_bytes)``, when given, is called once per
+        single-device dispatch with the bytes of the results read back
+        (``BatchServer`` counts them in its ``ServeStats``).  The sharded
+        pipeline does not call it.
 
         Quantised archives (``EngineConfig.archive_precision`` = "bfloat16"
         / "int8", staged via ``DeviceArchive.stage(precision=...)`` or a
@@ -305,8 +309,9 @@ class RecommendationEngine:
         requests = list(requests)
         if not requests:
             return []
-        t0 = time.perf_counter()
-        batch = RequestBatch.from_requests(cands, requests, pad_to=pad_to)
+        with tracing.span("repro.engine.masks", rows=len(requests),
+                          bucket=max(pad_to or 0, len(requests))):
+            batch = RequestBatch.from_requests(cands, requests, pad_to=pad_to)
         # Defensive re-check of the empty-filter contract: from_requests
         # raises per row, but the invariant is load-bearing enough (see the
         # docstring) to hold against any future batch constructor too.
@@ -325,18 +330,24 @@ class RecommendationEngine:
                                                          len(cands))))
             return self._build_recommendations(
                 cands, batch, requests, comb, avail, cost, order, counts,
-                k_stop, time.perf_counter() - t0)
-        operands, statics = self._fused_operands(cands, batch, archive)
-        comb, avail, cost, order, counts, k_stop, _ = jax.device_get(
-            _fused_recommend_batch(*operands, **statics))
+                k_stop)
+        statics = self._fused_statics(cands, archive)
+        uniq = (_dedup_masks(batch.masks)
+                if statics["score_impl"] == "tiled" else None)
+        with tracing.span("repro.engine.dispatch"):
+            out = _fused_recommend_batch(
+                *self._fused_operands(cands, batch, uniq, archive), **statics)
+        d2h = sum(x.nbytes for x in out)
+        with tracing.span("repro.engine.readback", d2h_bytes=d2h):
+            comb, avail, cost, order, counts, k_stop, _ = jax.device_get(out)
+        if on_transfer is not None:
+            on_transfer(d2h)
         return self._build_recommendations(
-            cands, batch, requests, comb, avail, cost, order, counts, k_stop,
-            time.perf_counter() - t0)
+            cands, batch, requests, comb, avail, cost, order, counts, k_stop)
 
-    def _fused_operands(self, cands: CandidateSet, batch: RequestBatch,
-                        archive=None):
-        """``(operands, static kwargs)`` of the single-device fused dispatch
-        :meth:`recommend_batch` makes for ``batch``."""
+    def _fused_statics(self, cands: CandidateSet, archive=None) -> dict:
+        """The static kwargs of the single-device fused dispatch: which
+        Algorithm 1 scan and which scoring stage run at this K."""
         impl = pool_lib.resolve_pool_impl(self.pool_impl, len(cands))
         s_impl = scoring.resolve_score_impl(self.score_impl, len(cands))
         if (s_impl == "dense" and archive is not None
@@ -345,9 +356,18 @@ class RecommendationEngine:
             # (repro.stream.ArchiveSnapshot) — they can only feed the tiled
             # stage, whatever the auto threshold says at this K.
             s_impl = "tiled"
-        if s_impl == "tiled":
+        return {"pool_impl": impl, "score_impl": s_impl}
+
+    def _fused_operands(self, cands: CandidateSet, batch: RequestBatch,
+                        uniq, archive=None) -> tuple:
+        """The operands of the single-device fused dispatch for ``batch``.
+
+        ``uniq`` is ``_dedup_masks(batch.masks)`` under the tiled scoring
+        stage and ``None`` under the dense one.
+        """
+        if uniq is not None:
             stats = archive.score_stats() if archive is not None else None
-            uniq_masks, uniq_inv = _dedup_masks(batch.masks)
+            uniq_masks, uniq_inv = uniq
         else:
             stats = uniq_masks = uniq_inv = None
         if archive is not None:
@@ -368,51 +388,50 @@ class RecommendationEngine:
                 jnp.asarray(cands.prices, jnp.float32),
                 jnp.asarray(cands.vcpus, jnp.float32),
                 jnp.asarray(cands.memory_gb, jnp.float32))
-        operands = (t3, prices, vcpus, memory_gb, batch.masks, batch.use_cpus,
-                    batch.weights, batch.lams, batch.amounts, stats,
-                    uniq_masks, uniq_inv)
-        return operands, {"pool_impl": impl, "score_impl": s_impl}
+        return (t3, prices, vcpus, memory_gb, batch.masks, batch.use_cpus,
+                batch.weights, batch.lams, batch.amounts, stats,
+                uniq_masks, uniq_inv)
 
     def _build_recommendations(self, cands: CandidateSet, batch: RequestBatch,
                                requests, comb, avail, cost, order, counts,
-                               k_stop, solve_time: float) -> list[Recommendation]:
+                               k_stop) -> list[Recommendation]:
         """Materialise :class:`Recommendation`\\ s from the batched arrays.
 
         Shared tail of the single-device fused dispatch and the sharded
         pipeline — both hand in (B, K) host score rows plus the vmapped
-        Algorithm 1 outputs, and this loop applies the ``max_types`` cap,
-        exact float64 hourly-cost accounting, and the diagnostics contract
-        (``solve_time_s`` is the whole-batch wall time on every row).
+        Algorithm 1 outputs, and this loop applies the ``max_types`` cap
+        and exact float64 hourly-cost accounting.
         """
-        recs = []
-        for b, req in enumerate(requests):
-            sel = counts[b] > 0
-            idx = np.asarray(order[b])[sel].astype(np.int64)
-            cnt = np.asarray(counts[b])[sel].astype(np.int64)
-            caps = np.asarray(req.capacity_of(cands), np.float64)
-            idx, cnt = _apply_max_types(idx, cnt, comb[b], caps, req.amount,
-                                        req.max_types)
-            hourly = float((cands.prices[idx] * cnt).sum())
-            n_real = int(batch.masks[b].sum())
-            # Match the sequential path's iteration count: a stop at the first
-            # padded lane is the gathered scan running out of candidates, which
-            # greedy_pool_vectorized reports as argmax-of-all-false == 0 -> 1.
-            # (n_real == 0 cannot reach here — recommend_batch raises on
-            # all-masked rows before dispatch, see the empty-filter contract.)
-            iters = int(k_stop[b]) + 1 if int(k_stop[b]) < n_real else 1
-            recs.append(Recommendation(
-                names=cands.names[idx], regions=cands.regions[idx],
-                azs=cands.azs[idx], counts=cnt, combined=comb[b][idx],
-                availability=avail[b][idx], cost=cost[b][idx],
-                hourly_cost=hourly,
-                diagnostics={
-                    "candidates_considered": n_real,
-                    "greedy_iterations": iters,
-                    "solve_time_s": solve_time,
-                    "batch_size": batch.batch_size,
-                },
-            ))
-        self._emit_results(requests, recs)
+        with tracing.span("repro.engine.build", rows=len(requests)):
+            recs = []
+            for b, req in enumerate(requests):
+                sel = counts[b] > 0
+                idx = np.asarray(order[b])[sel].astype(np.int64)
+                cnt = np.asarray(counts[b])[sel].astype(np.int64)
+                caps = np.asarray(req.capacity_of(cands), np.float64)
+                idx, cnt = _apply_max_types(idx, cnt, comb[b], caps,
+                                            req.amount, req.max_types)
+                hourly = float((cands.prices[idx] * cnt).sum())
+                n_real = int(batch.masks[b].sum())
+                # Match the sequential path's iteration count: a stop at the
+                # first padded lane is the gathered scan running out of
+                # candidates, which greedy_pool_vectorized reports as
+                # argmax-of-all-false == 0 -> 1.  (n_real == 0 cannot reach
+                # here — recommend_batch raises on all-masked rows before
+                # dispatch, see the empty-filter contract.)
+                iters = int(k_stop[b]) + 1 if int(k_stop[b]) < n_real else 1
+                recs.append(Recommendation(
+                    names=cands.names[idx], regions=cands.regions[idx],
+                    azs=cands.azs[idx], counts=cnt, combined=comb[b][idx],
+                    availability=avail[b][idx], cost=cost[b][idx],
+                    hourly_cost=hourly,
+                    diagnostics={
+                        "candidates_considered": n_real,
+                        "greedy_iterations": iters,
+                        "batch_size": batch.batch_size,
+                    },
+                ))
+            self._emit_results(requests, recs)
         return recs
 
     def score_archive(self, archive, *, lam: float = scoring.DEFAULT_LAMBDA,

@@ -13,7 +13,7 @@ This package closes that:
 - :mod:`harness` — a closed-loop virtual-time driver: arrivals replay on a
   virtual clock, each drain's service time is the measured wall time of the
   real batched dispatch, and every ticket's end-to-end latency streams into
-  the lock-guarded histograms on ``AdmissionStats``/``ServeStats``.
+  the lock-guarded histograms on ``AdmissionStats``.
 
 ``benchmarks/latency_slo.py`` runs the {steady, diurnal, bursty} x
 {filterless, distinct-mask} matrix plus a 2x-overload shedding scenario and

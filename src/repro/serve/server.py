@@ -33,15 +33,14 @@ splitting the K axis to fit the B x K x K buffer.  Configure with
 from __future__ import annotations
 
 import threading
-import time
 import warnings
 from dataclasses import dataclass, field
 
+from .. import tracing
 from ..core.config import (APIDeprecationWarning, EngineConfig,
                            resolve_engine_config)
 from ..core.engine import RecommendationEngine
 from ..core.types import CandidateSet, Recommendation
-from .histogram import LatencyHistogram
 
 DEFAULT_BUCKETS = (1, 8, 64, 256)
 
@@ -54,18 +53,16 @@ class ServeStats:
     is reached concurrently by the admission worker thread and direct
     callers, and unsynchronized ``+=`` on the counters would drop updates.
 
-    ``latency`` is the streaming histogram of whole-call service times —
-    one sample per ``serve`` call, covering every chunk the call dispatched
-    (batch assembly through device read-back).  It is the *service-time*
-    half of the latency story; the *end-to-end* half (queueing included)
-    lives on :class:`~repro.stream.AdmissionStats`.
+    ``d2h_bytes`` is the bytes of the results read back, summed over
+    single-device dispatches (a K-sharded archive's pipeline is not
+    counted).
     """
 
     requests: int = 0
     batches: int = 0
     padded_slots: int = 0
+    d2h_bytes: int = 0
     bucket_counts: dict = field(default_factory=dict)   # bucket size -> #batches
-    latency: LatencyHistogram = field(default_factory=LatencyHistogram)
 
     def record(self, n_requests: int, bucket: int) -> None:
         self.requests += n_requests
@@ -195,19 +192,24 @@ class BatchServer:
                 "serve() target must be a CandidateSet or a staged archive "
                 f"(DeviceArchive / rolling / snapshot / sharded), got "
                 f"{type(target).__name__}")
-        t0 = time.perf_counter()
         out: list[Recommendation] = []
         pos = 0
-        for chunk_len, bucket in self.plan_chunks(len(requests)):
-            chunk = requests[pos:pos + chunk_len]
-            pos += chunk_len
-            out.extend(self.engine.recommend_batch(
-                archive.host, chunk, pad_to=bucket, archive=archive))
-            with self._stats_lock:
-                self.stats.record(chunk_len, bucket)
-        with self._stats_lock:
-            self.stats.latency.record(time.perf_counter() - t0)
+        chunks = self.plan_chunks(len(requests))
+        with tracing.span("repro.serve", rows=len(requests),
+                          chunks=len(chunks)):
+            for chunk_len, bucket in chunks:
+                chunk = requests[pos:pos + chunk_len]
+                pos += chunk_len
+                out.extend(self.engine.recommend_batch(
+                    archive.host, chunk, pad_to=bucket, archive=archive,
+                    on_transfer=self._record_transfer))
+                with self._stats_lock:
+                    self.stats.record(chunk_len, bucket)
         return out
+
+    def _record_transfer(self, d2h: int) -> None:
+        with self._stats_lock:
+            self.stats.d2h_bytes += d2h
 
     def serve_archive(self, archive, requests) -> list[Recommendation]:
         """Deprecated alias: ``serve`` now dispatches on the operand type."""

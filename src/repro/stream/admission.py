@@ -46,10 +46,12 @@ synchronous force-drain when no worker is running.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
 
+from .. import tracing
 from ..serve.archive import PoolCache
 from ..serve.histogram import LatencyHistogram
 from ..serve.server import BatchServer
@@ -208,6 +210,7 @@ class AdmissionQueue:
                            else None)
         self.stats = AdmissionStats()
         self._pending: list[Ticket] = []
+        self._drain_seq = 0         # drains begun; names each drain's span
         self._lock = threading.Lock()
         self._wake = threading.Condition(self._lock)
         self._worker: threading.Thread | None = None
@@ -319,54 +322,61 @@ class AdmissionQueue:
         worker and direct callers both live to drain again.
         """
         now = self.clock() if now is None else now
-        with self._lock:
-            if not self._pending or not (force or any(
-                    t.deadline <= now for t in self._pending)
-                    or len(self._pending) >= self.max_pending):
-                return 0
-            cap = max(self.server.bucket_sizes)
-            if not force and self.adaptive and len(self._pending) > cap:
-                order = sorted(range(len(self._pending)),
-                               key=lambda i: self._pending[i].deadline)
-                take = set(order[:cap])
-                batch = [t for i, t in enumerate(self._pending) if i in take]
-                self._pending = [t for i, t in enumerate(self._pending)
-                                 if i not in take]
-            else:
-                batch, self._pending = self._pending, []
-        try:
-            archive = self.resolve_archive()
-            recs = self.server.serve(archive, [t.request for t in batch])
-        except Exception as err:  # noqa: BLE001 — fail the tickets, not the loop
+        with contextlib.ExitStack() as opened:
             with self._lock:
-                self.stats.drains += 1
-                self.stats.failed_drains += 1
-                self.stats.failed += len(batch)
-                if force:
-                    self.stats.forced_drains += 1
-            for t in batch:
-                t._resolve(error=err)
+                if not self._pending or not (force or any(
+                        t.deadline <= now for t in self._pending)
+                        or len(self._pending) >= self.max_pending):
+                    return 0
+                self._drain_seq += 1
+                span = opened.enter_context(tracing.span(
+                    "repro.admission.drain", drain=self._drain_seq))
+                cap = max(self.server.bucket_sizes)
+                if not force and self.adaptive and len(self._pending) > cap:
+                    order = sorted(range(len(self._pending)),
+                                   key=lambda i: self._pending[i].deadline)
+                    take = set(order[:cap])
+                    batch = [t for i, t in enumerate(self._pending)
+                             if i in take]
+                    self._pending = [t for i, t in enumerate(self._pending)
+                                     if i not in take]
+                else:
+                    batch, self._pending = self._pending, []
+                span.set_metadata(taken=len(batch),
+                                  pending=len(self._pending))
+            try:
+                archive = self.resolve_archive()
+                recs = self.server.serve(archive, [t.request for t in batch])
+            except Exception as err:  # noqa: BLE001 — fail the tickets, not the loop
+                with self._lock:
+                    self.stats.drains += 1
+                    self.stats.failed_drains += 1
+                    self.stats.failed += len(batch)
+                    if force:
+                        self.stats.forced_drains += 1
+                for t in batch:
+                    t._resolve(error=err)
+                return len(batch)
+            n_early = sum(1 for t in batch if t.deadline > now)
+            key = getattr(archive, "key", "?")
+            version = getattr(archive, "version", None)
+            stale = bool(getattr(archive, "stale", False))
+            done = self.clock()  # after service: end-to-end, not queueing-only
+            latencies = []
+            for t, rec in zip(batch, recs):
+                rec.diagnostics["archive_key"] = key
+                rec.diagnostics["degraded"] = False
+                rec.diagnostics["stale_archive"] = stale
+                if version is not None:
+                    rec.diagnostics["archive_version"] = version
+                if self.pool_cache is not None:
+                    self.pool_cache.put(t.request, rec)
+                latencies.append(max(0.0, done - t.submitted_at))
+                t._resolve(result=rec)
+            with self._lock:    # stats share the drain lock (see AdmissionStats)
+                self.stats.record_drain(len(batch), n_early, key,
+                                        forced=force, latencies=latencies)
             return len(batch)
-        n_early = sum(1 for t in batch if t.deadline > now)
-        key = getattr(archive, "key", "?")
-        version = getattr(archive, "version", None)
-        stale = bool(getattr(archive, "stale", False))
-        done = self.clock()     # after service: end-to-end, not queueing-only
-        latencies = []
-        for t, rec in zip(batch, recs):
-            rec.diagnostics["archive_key"] = key
-            rec.diagnostics["degraded"] = False
-            rec.diagnostics["stale_archive"] = stale
-            if version is not None:
-                rec.diagnostics["archive_version"] = version
-            if self.pool_cache is not None:
-                self.pool_cache.put(t.request, rec)
-            latencies.append(max(0.0, done - t.submitted_at))
-            t._resolve(result=rec)
-        with self._lock:        # stats share the drain lock (see AdmissionStats)
-            self.stats.record_drain(len(batch), n_early, key, forced=force,
-                                    latencies=latencies)
-        return len(batch)
 
     # -- background operation ----------------------------------------------
 

@@ -166,6 +166,7 @@ def test_threaded_admission_serving_is_race_free(racecheck, cands):
         q.stop()
     assert q.stats.submitted == 20 and q.stats.served == 20
     assert server.stats.requests == 20
+    assert server.stats.d2h_bytes > 0
     assert racecheck.problems() == []     # fixture re-checks at teardown
 
 

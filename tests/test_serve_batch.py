@@ -195,12 +195,14 @@ def test_empty_batch(cands, engine):
 
 
 def test_solve_time_is_whole_batch_wall_time(cands, engine):
-    """Documented diagnostics contract: solve_time_s is one wall-time
-    figure for the whole batch, stamped identically on every request."""
+    """Diagnostics contract of the batch path: every row carries the
+    padded ``batch_size`` it rode in and no wall time (the batch path is
+    timed by its ``repro.engine.*`` spans, not by a stamp on each row)."""
     reqs = heterogeneous_requests(cands)[:4]
-    recs = engine.recommend_batch(cands, reqs)
-    assert len({r.diagnostics["solve_time_s"] for r in recs}) == 1
-    assert all(r.diagnostics["batch_size"] == 4 for r in recs)
+    for pad_to, size in ((None, 4), (8, 8)):
+        recs = engine.recommend_batch(cands, reqs, pad_to=pad_to)
+        assert all(r.diagnostics["batch_size"] == size for r in recs)
+        assert all("solve_time_s" not in r.diagnostics for r in recs)
 
 
 def test_request_batch_padding_shape(cands):
