@@ -157,8 +157,13 @@ def _prefix_allocations(s: jax.Array, c: jax.Array, required: jax.Array,
     tri = jnp.tril(jnp.ones((K, K), bool))
     X = jnp.where(tri, X, 0)
 
+    # Rows and the diagonal are picked by masked integer sums (exact: one
+    # term, zeros elsewhere), because a per-row dynamic index or
+    # ``jnp.diagonal`` is a gather under the batched engine's vmap.
+    rows = jnp.arange(K, dtype=jnp.int32)[:, None]
     top = X[:, 0]                                            # (K,) top-ranked alloc per prefix
-    newest = jnp.diagonal(X)                                 # (K,) newest member's alloc
+    newest = jnp.sum(jnp.where(rows == rows.T, X, 0), axis=1,
+                     dtype=jnp.int32)                        # (K,) newest member's alloc
     prev_top = jnp.concatenate([jnp.array([jnp.iinfo(jnp.int32).max],
                                           jnp.int32), top[:-1]])
     terminate = (top >= prev_top) | (newest == 0)
@@ -166,7 +171,8 @@ def _prefix_allocations(s: jax.Array, c: jax.Array, required: jax.Array,
     any_term = jnp.any(terminate)
     k_stop = jnp.argmax(terminate)                           # first terminating prefix
     k_best = jnp.where(any_term, jnp.maximum(k_stop - 1, 0), K - 1)
-    counts_sorted = X[k_best]
+    counts_sorted = jnp.sum(jnp.where(rows == k_best, X, 0), axis=0,
+                            dtype=jnp.int32)                 # X[k_best]
     # Degenerate guard: if termination fired at k=0 keep the single-type pool.
     fallback = jnp.zeros_like(counts_sorted).at[0].set(
         jnp.ceil(required / c[0]).astype(jnp.int32))
@@ -174,12 +180,35 @@ def _prefix_allocations(s: jax.Array, c: jax.Array, required: jax.Array,
     return counts_sorted, k_stop, any_term
 
 
+def _sort_candidates(scores: jax.Array, cpus: jax.Array, mask: jax.Array):
+    """Stable score-descending order of the ``mask`` lanes of one candidate row.
+
+    Masked-out lanes take the sort key ``+inf``, so they follow every valid
+    one.  The capacities and the mask ride through the same stable sort as
+    payloads, and the scores come back as the negated sorted key (negation
+    is exact), so nothing is gathered by ``order``: under the batched
+    engine's vmap such a gather was ~96% of the served program's device
+    time.  The permutation is ``jnp.argsort(key, stable=True)``'s bit for
+    bit: both are one ``lax.sort`` on the key with an iota of the default
+    integer dtype.  Returns ``(order, s, c, mask_sorted)``: ``s`` is 0 and
+    ``c`` is 1 on masked-out lanes, both in the enabled float precision.
+    """
+    x64 = jax.config.read("jax_enable_x64")
+    dtype = jnp.float64 if x64 else jnp.float32
+    iota = jnp.arange(scores.shape[0], dtype=jnp.int64 if x64 else jnp.int32)
+    key = jnp.where(mask, -scores, jnp.inf)
+    key, order, c, mask_sorted = jax.lax.sort(
+        (key, iota, cpus, mask), num_keys=1, is_stable=True)
+    s = jnp.where(mask_sorted, -key, 0.0).astype(dtype)
+    c = jnp.where(mask_sorted, c, 1.0).astype(dtype)
+    return order, s, c, mask_sorted
+
+
 @functools.partial(jax.jit, static_argnames=("impl", "tile"))
 def _greedy_pool_core(scores: jax.Array, cpus: jax.Array, required: jax.Array,
                       *, impl: str = "dense", tile: int | None = None):
-    order = jnp.argsort(-scores, stable=True)
-    s = scores[order].astype(jnp.float64 if jax.config.read("jax_enable_x64") else jnp.float32)
-    c = cpus[order].astype(s.dtype)
+    order, s, c, _ = _sort_candidates(scores, cpus,
+                                      jnp.ones(scores.shape, bool))
     counts_sorted, k_stop, any_term = _prefix_allocations(
         s, c, required, impl=impl, tile=tile)
     return order, counts_sorted, k_stop, any_term
@@ -205,12 +234,7 @@ def greedy_pool_masked(scores: jax.Array, cpus: jax.Array, required: jax.Array,
     docstring); it must be resolved (not "auto") by the caller because the
     choice is trace-static.
     """
-    dtype = jnp.float64 if jax.config.read("jax_enable_x64") else jnp.float32
-    key = jnp.where(mask, -scores, jnp.inf)
-    order = jnp.argsort(key, stable=True)
-    mask_sorted = mask[order]
-    s = jnp.where(mask_sorted, scores[order], 0.0).astype(dtype)
-    c = jnp.where(mask_sorted, cpus[order], 1.0).astype(dtype)
+    order, s, c, _ = _sort_candidates(scores, cpus, mask)
     counts_sorted, k_stop, any_term = _prefix_allocations(
         s, c, required, impl=impl, tile=tile)
     return order, counts_sorted, k_stop, any_term

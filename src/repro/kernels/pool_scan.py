@@ -164,7 +164,10 @@ def _pool_scan_lax(s: jax.Array, c: jax.Array, required: jax.Array):
     first = _tile_stats(s, c, csc, _lagged(csc), lane, s0, c0, required, K)
     any_term, k_stop, k_best = _finalize(first, K)
     deg = any_term & (k_stop == 0)
-    counts = _emit_row(s, c, required, csc[k_best], k_best, deg, c0, lane)
+    # csc[k_best] as a masked sum, as the Pallas kernel reads it: exact, as
+    # csc is positive, and no per-row dynamic index (a gather under vmap).
+    stot_best = jnp.sum(jnp.where(lane == k_best, csc, 0.0))
+    counts = _emit_row(s, c, required, stot_best, k_best, deg, c0, lane)
     return counts, k_stop, any_term
 
 

@@ -49,3 +49,17 @@ def random_mask(seed: int, n_valid: int) -> np.ndarray:
 def as_jax(scores, cpus, required, mask):
     return (jnp.asarray(scores, jnp.float32), jnp.asarray(cpus, jnp.float32),
             jnp.float32(required), jnp.asarray(mask))
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "tile"))
+def gathered_pool(scores, cpus, required, mask, *, impl, tile=None):
+    """Reference ordering: ``jnp.argsort`` of the sort key, then scores,
+    capacities and mask gathered by ``order`` (what the pool stage did
+    before the payloads rode through the sort)."""
+    key = jnp.where(mask, -scores, jnp.inf)
+    order = jnp.argsort(key, stable=True)
+    mask_sorted = mask[order]
+    s = jnp.where(mask_sorted, scores[order], 0.0).astype(scores.dtype)
+    c = jnp.where(mask_sorted, cpus[order], 1.0).astype(scores.dtype)
+    return (order,) + pool_lib._prefix_allocations(s, c, required,
+                                                   impl=impl, tile=tile)

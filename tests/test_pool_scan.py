@@ -21,7 +21,7 @@ from repro.core import pool as pool_lib
 from repro.kernels import pool_scan as pool_scan_lib
 
 from _pool_helpers import (KW, PALLAS_TILE, TILE, adversarial_instance,
-                           as_jax, masked_pool)
+                           as_jax, gathered_pool, masked_pool)
 
 
 def test_all_masked_row_matches_dense():
@@ -31,6 +31,69 @@ def test_all_masked_row_matches_dense():
     tiled = jax.device_get(masked_pool(*args, impl="tiled", tile=TILE))
     for a, b in zip(dense, tiled):
         np.testing.assert_array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# the one sort carries scores, capacities and mask: same pools as gathering
+# them by the argsort's order, and no gather left in the batched program
+# ---------------------------------------------------------------------------
+
+def _sort_case(name):
+    """(scores, cpus, mask) of one ordering edge case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    k = 2 * PALLAS_TILE if name == "tile_boundary" else KW
+    scores = rng.uniform(0.1, 100.0, k)
+    cpus = rng.choice([2, 4, 8, 16, 32, 48, 64, 96], k).astype(float)
+    mask = rng.random(k) < 0.7
+    if name == "heavy_ties":
+        scores = rng.choice([1.0, 2.5, 4.0], k)
+    elif name == "masked_tail":
+        mask[k // 2:] = False
+        scores[k // 2:] = 1e3              # would lead the order unmasked
+    elif name == "single_valid":
+        mask = np.zeros(k, bool)
+        mask[k // 3] = True
+    elif name == "nan_score":
+        mask[5] = True
+        scores[5] = np.nan
+    return scores, cpus, mask
+
+
+SORT_CASES = ("heavy_ties", "masked_tail", "single_valid", "tile_boundary",
+              "nan_score")
+
+
+@pytest.mark.parametrize("impl", ["dense", "tiled"])
+@pytest.mark.parametrize("case", SORT_CASES)
+def test_sort_payloads_match_argsort_gather(case, impl):
+    scores, cpus, mask = _sort_case(case)
+    args = as_jax(scores, cpus, 129.25, mask)
+    got = jax.device_get(masked_pool(*args, impl=impl))
+    want = jax.device_get(gathered_pool(*args, impl=impl))
+    for x, y in zip(got, want):             # order, counts, k_stop, any_term
+        assert np.asarray(x).dtype == np.asarray(y).dtype
+        np.testing.assert_array_equal(x, y)
+    order, s, c, mask_sorted = jax.device_get(
+        jax.jit(pool_lib._sort_candidates)(*args[:2], args[3]))
+    np.testing.assert_array_equal(order, got[0])
+    np.testing.assert_array_equal(mask_sorted, mask[order])
+    np.testing.assert_array_equal(
+        s, np.where(mask[order], np.float32(scores)[order], 0.0))
+    np.testing.assert_array_equal(
+        c, np.where(mask[order], np.float32(cpus)[order], 1.0))
+
+
+@pytest.mark.parametrize("impl", ["dense", "tiled"])
+def test_batched_pool_lowers_without_gather(impl):
+    """A gather by per-row sort order under vmap was ~96% of the served
+    program's device time; none may come back into the batched pool."""
+    B, k = 3, KW
+    fn = jax.vmap(functools.partial(pool_lib.greedy_pool_masked, impl=impl,
+                                    tile=TILE))
+    text = jax.jit(fn).lower(
+        jnp.zeros((B, k), jnp.float32), jnp.ones((B, k), jnp.float32),
+        jnp.ones((B,), jnp.float32), jnp.ones((B, k), bool)).as_text()
+    assert "gather" not in text
 
 
 # ---------------------------------------------------------------------------
