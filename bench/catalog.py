@@ -104,20 +104,25 @@ def _walk(key, *, k: int, steps: int, lo, hi, p_lo, p_hi, revert):
 class Deployment:
     cands: CandidateSet        # the catalog and its (K, window) float32 T3
     window: int
+    ticks: np.ndarray | None = None   # (K, n) float32: the columns after it
 
 
-def build(cfg: dict, seed: int) -> Deployment:
-    """The configuration's catalog and T3 series for ``seed``."""
+def build(cfg: dict, seed: int, ticks: int = 0) -> Deployment:
+    """The configuration's catalog and T3 series for ``seed``.
+
+    ``ticks`` more columns of the same walk follow the window: the columns
+    a live collector appends, one per tick (``Deployment.ticks``).
+    """
     lay = layout(cfg)
     k, t = len(lay.names), int(cfg["window"])
     walk = cfg["t3_walk"]
     key = jax.random.wrap_key_data(jnp.asarray(seed_words(seed, 0)))
     levels = np.asarray(jax.device_get(_walk(
-        key, k=k, steps=t, lo=walk["level"][0], hi=walk["level"][1],
+        key, k=k, steps=t + ticks, lo=walk["level"][0], hi=walk["level"][1],
         p_lo=walk["p_move"][0], p_hi=walk["p_move"][1],
         revert=walk["revert"])))
     step = float(cfg["t3_counts"][2])
-    series = levels.astype(np.float32) * np.float32(step)
+    series = levels[:, :t].astype(np.float32) * np.float32(step)
     pr = cfg["prices"]
     rng = np.random.default_rng([seed, 1])
     n_keys = int(lay.price_key.max()) + 1
@@ -131,4 +136,6 @@ def build(cfg: dict, seed: int) -> Deployment:
         names=lay.names, regions=lay.regions, azs=lay.azs,
         families=lay.families, categories=lay.categories, vcpus=lay.vcpus,
         memory_gb=lay.memory_gb, prices=prices, t3=series)
-    return Deployment(cands, t)
+    extra = (levels[:, t:].astype(np.float32) * np.float32(step)
+             if ticks else None)
+    return Deployment(cands, t, extra)

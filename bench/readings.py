@@ -8,8 +8,9 @@ For each seed it makes a run of the cell at the cell's own load and sizes
 (a short window: the readings need no more than the answers a run checks)
 and writes one JSON line: the numbers the check compares for the program,
 and for the control, the plain reference computed in the next precision
-below the configuration's (``bfloat16`` for float32) and put in the
-program's place on the same sampled requests.  The lower
+below the configuration's (``bfloat16`` for a float32 archive, ``int4``
+for an int8 tier, each answer from the window of the version that served
+it) and put in the program's place on the same sampled requests.  The lower
 reading of a number is the largest the program gives; the upper, the
 smallest the control gives.  The benchmark's own runs never run this.
 """
@@ -30,7 +31,8 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--seconds", type=float, default=8.0)
-    ap.add_argument("--control", choices=("bfloat16",), required=True)
+    ap.add_argument("--control", choices=("bfloat16", "int4"),
+                    required=True)
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]

@@ -13,6 +13,12 @@ from the benchmark's own data and importing nothing of the program:
 ``precision="bfloat16"`` switches the reference to the control of the
 output check: the statistics and scores in bfloat16, the step below the
 float32 archive.
+
+A rolling integer tier (``TierWindows``) is followed version by version:
+every window is held as the tier documents that it stores it (codes of a
+per-candidate step fixed when the ring is primed, clipped to the code
+range) and its statistics are float64.  ``bits=4`` is the control of an
+int8 tier.
 """
 from __future__ import annotations
 
@@ -62,6 +68,85 @@ def _stats_bf16(y: np.ndarray):
     std = jnp.sqrt(jnp.mean((x - mean) ** 2, axis=1, dtype=bf))
     return (np.asarray(area, np.float64), np.asarray(slope, np.float64),
             np.asarray(std, np.float64))
+
+
+def tier_scale(t3: np.ndarray, headroom: float, bits: int) -> np.ndarray:
+    """Per-candidate step of an integer tier, float32: the largest magnitude
+    of the primed window x ``headroom``, over the top code
+    ``2**(bits-1) - 1``."""
+    top = 2 ** (bits - 1) - 1
+    maxabs = np.empty(t3.shape[0], np.float32)
+    for a in range(0, t3.shape[0], BLOCK):
+        maxabs[a:a + BLOCK] = np.abs(t3[a:a + BLOCK]).max(axis=1)
+    return (np.maximum(maxabs * np.float32(headroom), np.float32(1e-12))
+            * np.float32(1.0 / top))
+
+
+def tier_decode(y: np.ndarray, scale: np.ndarray, bits: int) -> np.ndarray:
+    """Values as the tier stores them: the code ``round(y / step)`` (half to
+    even, float32), clipped to +-``2**(bits-1) - 1``, times the step.
+    ``scale`` broadcasts against ``y``."""
+    top = 2 ** (bits - 1) - 1
+    codes = np.clip(np.round(np.asarray(y, np.float32) / scale), -top, top)
+    return codes.astype(np.float32) * scale
+
+
+class TierWindows:
+    """``(area, slope, std)`` of every version of a rolling tier's window.
+
+    Version ``v`` holds columns ``v .. v + T - 1`` of the primed window
+    ``t3`` (K, T) followed by ``ticks`` (K, n): each tick absorbed is one
+    version.  The first window is summed directly; each later version
+    slides by one column, adding the new and dropping the oldest, all in
+    float64 with the index counted from the primed window's first column.
+    Versions are visited in increasing order.
+    """
+
+    def __init__(self, t3: np.ndarray, ticks: np.ndarray, headroom: float,
+                 bits: int):
+        self.t3, self.ticks, self.bits = t3, ticks, bits
+        k, self.n = t3.shape
+        self.scale = tier_scale(t3, headroom, bits)
+        self.s0, self.s1, self.q = np.empty(k), np.empty(k), np.empty(k)
+        self.ref = np.empty(k)
+        j = np.arange(self.n, dtype=np.float64)
+        for a in range(0, k, BLOCK):
+            b = min(a + BLOCK, k)
+            y = tier_decode(t3[a:b], self.scale[a:b, None],
+                            bits).astype(np.float64)
+            self.ref[a:b] = y.mean(axis=1)
+            self.s0[a:b] = y.sum(axis=1)
+            self.s1[a:b] = y @ j
+            self.q[a:b] = ((y - self.ref[a:b, None]) ** 2).sum(axis=1)
+        self.version = 0
+
+    def column(self, j: int) -> np.ndarray:
+        """Column ``j`` (counted from the primed window's first) as the tier
+        stores it, float64."""
+        y = self.t3[:, j] if j < self.n else self.ticks[:, j - self.n]
+        return tier_decode(y, self.scale, self.bits).astype(np.float64)
+
+    def stats(self, version: int):
+        if version < self.version:
+            raise ValueError(f"version {version} after {self.version}")
+        if version - self.version > self.ticks.shape[1]:
+            raise ValueError(f"version {version}: only "
+                             f"{self.ticks.shape[1]} ticks")
+        while self.version < version:
+            v = self.version
+            old, new = self.column(v), self.column(v + self.n)
+            self.s0 += new - old
+            self.s1 += (v + self.n) * new - v * old
+            self.q += (new - self.ref) ** 2 - (old - self.ref) ** 2
+            self.version += 1
+        v, n = self.version, self.n
+        mean = self.s0 / n
+        t_c = np.arange(n) - (n - 1) / 2.0
+        denom = t_c @ t_c if n > 1 else 1.0
+        area = self.s0 - 0.5 * (self.column(v) + self.column(v + n - 1))
+        slope = (self.s1 - (v + (n - 1) / 2.0) * self.s0) / denom
+        var = self.q / n - (mean - self.ref) ** 2
+        return area, slope, np.sqrt(np.maximum(var, 0.0))
 
 
 FILTERS = ("regions", "azs", "families", "categories", "types")

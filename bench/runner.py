@@ -7,6 +7,13 @@ them through ``BatchServer.serve`` into ``RecommendationEngine
 thread that submits each request at its due time, a watcher thread that
 stamps each result as it resolves, and a thin proxy that times ``serve``.
 
+A live deployment (archive kind ``rolling``) also runs the program's
+ingestion loop: ``LiveIngestor`` primes a ``RollingDeviceArchive`` from a
+seeded collector (``bench/live.py``), every drain serves a version-pinned
+snapshot of it, and ``IngestPump`` appends a column every
+``ingest.period_s`` through the window.  The proxy records the version each
+request was served from, and the harness when each version was published.
+
 Every latency runs from the time a request was due, so a late generator or
 a stall anywhere shows in it.  Tails are taken from every raw sample.
 
@@ -30,7 +37,8 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
-from bench import catalog, check, trace as trace_lib, traffic as traffic_lib
+from bench import catalog, check, live as live_lib, trace as trace_lib, \
+    traffic as traffic_lib
 
 CLOCK = time.monotonic
 TRACE_AT = 0.3          # the traced stretch starts this far into the window
@@ -89,6 +97,37 @@ class ServeProxy:
         return out
 
 
+class LiveServeProxy(ServeProxy):
+    """``ServeProxy`` that also records the archive version of each serve,
+    per request (``versions``) and per call (``call_versions``), and when
+    the drain's snapshot was taken (``snapped``), from the queue's
+    ``resolve_archive`` as ``timed`` wraps it."""
+
+    def __init__(self, server, annotate):
+        super().__init__(server, annotate)
+        self.versions: dict = {}         # id(request) -> version served
+        self.snapped: dict = {}          # id(request) -> (began, ended)
+        self.call_versions: list = []
+        self._last = (float("nan"), float("nan"))
+
+    def timed(self, resolve):
+        """``resolve`` (a drain's snapshot) timed from call to return."""
+        def resolve_archive():
+            t0 = CLOCK()
+            snap = resolve()
+            self._last = (t0, CLOCK())
+            return snap
+        return resolve_archive
+
+    def serve(self, target, requests):
+        for r in requests:
+            self.versions[id(r)] = target.version
+            self.snapped[id(r)] = self._last
+        out = super().serve(target, requests)
+        self.call_versions.append(target.version)
+        return out
+
+
 @dataclass
 class Run:
     """What one run recorded; the per-layer readers take their numbers
@@ -140,6 +179,88 @@ class System:
             self.server.serve(archive,
                               warm_requests(bucket, u, presets, requests))
         jax.effects_barrier()
+
+
+class LiveSystem(System):
+    """A live deployment, built from the program's own pieces:
+    ``LiveIngestor(collector).prime()`` stages the rolling archive at the
+    configuration's tier, the admission queue serves snapshots of it, and
+    an ``IngestPump`` (started and stopped by the run) appends a column
+    every ``ingest.period_s``.  The pump's poll is wrapped to record when
+    each version's poll began and returned (``began``, ``published``), and
+    the queue's ``resolve_archive`` to time each drain's snapshot."""
+
+    def __init__(self, cfg: dict, dep: catalog.Deployment, annotate):
+        from repro.core import EngineConfig
+        from repro.serve import BatchServer
+        from repro.stream import AdmissionQueue, IngestPump, LiveIngestor
+        arc = cfg["archive"]
+        self.annotate = annotate
+        self.server = BatchServer(config=EngineConfig())
+        self.proxy = LiveServeProxy(self.server, annotate)
+        self.stand_in = live_lib.Collector(dep)
+        self.ingestor = LiveIngestor(
+            self.stand_in, window=dep.window, precision=arc["precision"],
+            headroom=float(arc["headroom"]))
+        t = CLOCK()
+        self.archive = self.ingestor.prime()
+        self.began, self.published = {0: t}, {0: CLOCK()}
+        self._poll = self.ingestor.poll
+        self.ingestor.poll = self._publish
+        self.pump = IngestPump(self.ingestor, self.stand_in.collect,
+                               period=float(cfg["ingest"]["period_s"]))
+        self.queue = AdmissionQueue(
+            self.proxy, self.archive,
+            adaptive=bool(cfg["admission"]["adaptive"]), shed_depth=None)
+        self.queue.resolve_archive = self.proxy.timed(
+            self.queue.resolve_archive)
+
+    def _publish(self) -> int:
+        t0 = CLOCK()
+        with self.annotate("bench.tick"):
+            n = self._poll()
+        t1 = CLOCK()
+        for v in range(self.ingestor.version - n + 1,
+                       self.ingestor.version + 1):
+            self.began[v], self.published[v] = t0, t1
+        return n
+
+    def warm(self, shapes, presets, requests) -> None:
+        """Absorb one tick, so the append programs compile here, then
+        serve every warmed shape against a snapshot."""
+        self.stand_in.collect()
+        self.ingestor.poll()
+        super().warm(shapes, presets, requests)
+
+    def window(self, requests, sample, results, w0: float, w1: float):
+        """After the window: the version that served each sampled request,
+        the check's ``versions_off`` over every answer, and the window's
+        ingest numbers."""
+        p = self.proxy
+        versions = [p.versions.get(id(requests[i])) for i in sample]
+        stamps = {id(requests[i]): rec.diagnostics.get("archive_version")
+                  for i, rec in results.items()}
+        rows = [(p.versions[id(r)], stamps.get(id(r)), *p.snapped[id(r)])
+                for r in requests if id(r) in p.versions]
+        off = check.versions_off(rows, self.published, self.began)
+        calls = sorted(zip((c[0] for c in p.calls), p.call_versions))
+        fresh = []
+        for v, t in self.published.items():
+            if w0 <= t < w1:
+                first = next((a for a, cv in calls if a >= t and cv >= v),
+                             None)
+                if first is not None:
+                    fresh.append(first - t)
+        info = {"ticks": self.pump.ticks_pumped,
+                "pump_errors": self.pump.errors,
+                "clipped_samples": self.archive.clipped_samples,
+                "fresh_ms": (float(np.median(fresh)) * 1e3 if fresh
+                             else None),
+                "versions_served": [min(p.call_versions, default=None),
+                                    max(p.call_versions, default=None)]}
+        if self.pump.last_error is not None:
+            info["pump_error"] = repr(self.pump.last_error)
+        return versions, off, info
 
 
 def warm_requests(bucket: int, u: int, presets: list, requests: list):
@@ -264,12 +385,14 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool,
     annotate = (jax.profiler.TraceAnnotation if traced
                 else lambda _name: contextlib.nullcontext())
 
-    dep = catalog.build(cfg, seed)
+    live = cfg["archive"]["kind"] == "rolling"
+    dep = catalog.build(cfg, seed,
+                        live_lib.ticks_for(cfg, seconds) if live else 0)
     tr = traffic_lib.build(tcfg, dep.cands, seconds, seed)
     n = len(tr.requests)
     amounts = np.array([r.amount for r in tr.requests])
     sample = check.draw_sample(n, int(tcfg["check"]["sample"]), seed, amounts)
-    system = System(cfg, dep, annotate)
+    system = (LiveSystem if live else System)(cfg, dep, annotate)
     if fault is not None:
         fault(system)
     system.warm(tcfg["warm"], tr.presets, tr.requests)
@@ -284,6 +407,8 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool,
 
     loop = OpenLoop(system, tr, sample, annotate)
     loop.start()
+    if live:
+        system.pump.start()
     tr_dir = None
     if traced:
         span = min(TRACE_S, seconds / 3.0)
@@ -295,6 +420,8 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool,
         jax.profiler.stop_trace()
     w0, w1 = loop.w0, loop.w0 + seconds
     loop.close(w1)
+    if live:
+        system.pump.stop()
     # A latency cell's tail is the tail of every request due in the window,
     # so it waits for all of them; the throughput cell waits for its sample.
     waits_all = any(m["name"] == "p99_ms" for m in cell.end_to_end)
@@ -329,13 +456,23 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool,
     peak = int(mem.get("peak_bytes_in_use", 0))
     served = [(tr.requests[i], loop.results.get(i), loop.errors.get(i))
               for i in sample]
+    versions = ingest = None
+    if live:
+        versions, v_off, ingest = system.window(tr.requests, sample,
+                                                loop.results, w0, w1)
     del system, loop
     gc.unfreeze()
     gc.collect()
 
-    numbers = check.compare(cfg, dep, served)
+    numbers = check.compare(cfg, dep, served, versions)
     ctl = (check.compare(cfg, dep, check.control_answers(
-        dep, served, control)) if control else None)
+        cfg, dep, served, control, versions), versions)
+        if control else None)
+    if live:
+        numbers["versions_off"] = (float(v_off),
+                                   float(cfg["limits"]["versions_off"]))
+        if ctl is not None:
+            ctl["versions_off"] = numbers["versions_off"]
     if waits_all:
         numbers["unanswered"] = (float(n - ok.sum()),
                                  numbers["unanswered"][1])
@@ -374,6 +511,8 @@ def run_cell(bench, cell, seed: int, seconds: float, traced: bool,
                "serve_max_ms": max((b - a for a, b, _ in run.serve_calls
                                     if run.in_window(a)), default=0.0) * 1e3,
                "batches_by_bucket": counters["batches_by_bucket"]}}
+    if ingest is not None:
+        out["window"].update(ingest)
     if ctl is not None:
         out["control"] = {k: v for k, (v, _) in ctl.items()}
     if traced:

@@ -95,6 +95,8 @@ def main(argv=None) -> int:
                "p99_ms": out["metrics"]["p99_ms"]["value"],
                "late_p50_ms": w["late_p50_ms"],
                "late_max_ms": w["late_max_ms"], "correct": out["correct"]}
+        # a live deployment's ingest pump runs through every rate
+        row.update({k: w[k] for k in ("ticks", "pump_errors") if k in w})
         rows.append(row)
         print(json.dumps(row), flush=True)
         t = time.monotonic()

@@ -7,8 +7,12 @@ import time
 import pytest
 
 from bench import runner
+from conftest import LIVE_CELL
 
-CELLS = ["aws-32k-f32-adaptive.overload-distinct"]
+CELLS = ["aws-32k-f32-adaptive.overload-distinct", LIVE_CELL]
+# the reference in the precision below the configuration's archive
+CONTROL = {"aws-32k-f32-adaptive.overload-distinct": "bfloat16",
+           LIVE_CELL: "int4"}
 
 
 def run(bench, cell, fault=None, control=None, seed=2 ** 31 + 99):
@@ -30,7 +34,7 @@ def test_sound_run_is_correct(small, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_control_fails(small, cell):
-    out = run(small, cell, control="bfloat16")
+    out = run(small, cell, control=CONTROL[cell])
     limits = {k: v["limit"] for k, v in out["check"].items()}
     failed = [k for k, v in out["control"].items() if v > limits[k]]
     assert failed, (out["control"], limits)
