@@ -217,7 +217,7 @@ def run_single_chip(phase, k: int, t: int, ticks: int = TICKS):
             ring = RollingDeviceArchive(cands, precision=precision)
             jax.block_until_ready(ring.score_stats())
         step, ops, statics = ring._append_dispatch(
-            rng.uniform(0.0, 50.0, k))
+            ring._encode(rng.uniform(0.0, 50.0, k))[0])
         kernels |= compiled_kernels(
             step.lower(*ops, **statics).compile().as_text())
         worst = 0.0

@@ -313,3 +313,15 @@ def instrument_cmdb(registry: LockRegistry, cmdb, name: str = "cmdb"):
     registry.guard(cmdb, fields=("_next_id",), locks=(f"{name}._lock",),
                    label="PoolCMDB")
     return proxy
+
+
+def instrument_rolling_archive(registry: LockRegistry, archive,
+                               name: str = "ring"):
+    """Proxy ``RollingDeviceArchive._lock``; guard what ``append``
+    publishes and ``snapshot`` reads as one (cursor, length, statistics,
+    version)."""
+    proxy = registry.wrap(archive._lock, f"{name}._lock")
+    archive._lock = proxy
+    registry.guard(archive, fields=("_pos", "_len", "_stats", "version"),
+                   locks=(f"{name}._lock",), label="RollingDeviceArchive")
+    return proxy

@@ -101,9 +101,11 @@ def quantize_window(window, scale: np.ndarray, precision: str, *,
                     chunk: int = STAGE_CHUNK) -> np.ndarray:
     """Encode a host (K, T) window at ``precision`` (chunked, no full temp).
 
-    The float op sequence per sample matches :func:`quantize_column` exactly
-    (float32 divide, round-half-even, clip), so a staged window and a stream
-    of appended columns land on identical codes.
+    int8 codes are float32 ``y / scale`` rounded half to even and clipped to
+    +-127, all on the host: :func:`quantize_column` encodes a tick through
+    this function, so a staged window and a stream of appended columns land
+    on identical codes (a device divide need not round exactly, and at an
+    exact tie of ``y / scale`` one off is the other side of the tie).
     """
     resolve_precision(precision)
     window = np.asarray(window)
@@ -116,8 +118,8 @@ def quantize_window(window, scale: np.ndarray, precision: str, *,
         if precision == "bfloat16":
             out[a:b] = blk.astype(ml_dtypes.bfloat16)
         else:
-            codes = np.round(blk / scale[a:b, None].astype(np.float32))
-            out[a:b] = np.clip(codes, -127, 127).astype(np.int8)
+            out[a:b] = np.clip(_int8_round(blk, scale[a:b, None]),
+                               -127, 127).astype(np.int8)
     return out
 
 
@@ -134,23 +136,29 @@ def dequantize_window(q, scale, precision: str):
     return q.astype(jnp.float32)
 
 
-def quantize_column(col: jax.Array, scale: jax.Array, precision: str):
-    """Encode one (K,) tick column; returns ``(codes, n_clipped)``.
+def quantize_column(col, scale, precision: str):
+    """Encode one (K,) tick column on the host; returns ``(codes,
+    n_clipped)``.
 
-    jit-traceable — this is the device-side half of the rolling archives'
-    append path.  ``n_clipped`` counts samples outside the int8 clip range
+    The codes are :func:`quantize_window`'s of the (K, 1) window, so a tick
+    appended to a ring stores what a cold re-stage of the same window
+    would.  ``n_clipped`` counts samples whose code fell outside +-127
     (always 0 for bf16/f32): the error-bound contract only holds for
     unclipped samples, so the archives surface the count rather than
     silently saturating.
     """
-    col = jnp.asarray(col, jnp.float32)
-    if precision == "bfloat16":
-        return col.astype(jnp.bfloat16), jnp.int32(0)
-    if precision == "float32":
-        return col, jnp.int32(0)
-    codes = jnp.round(col / jnp.asarray(scale, jnp.float32))
-    clipped = jnp.sum((codes > 127) | (codes < -127)).astype(jnp.int32)
-    return jnp.clip(codes, -127, 127).astype(jnp.int8), clipped
+    col = np.asarray(col, np.float32)
+    scale = np.asarray(scale, np.float32)
+    codes = quantize_window(col[:, None], scale, precision)[:, 0]
+    if precision != "int8":
+        return codes, 0
+    return codes, int(np.count_nonzero(np.abs(_int8_round(col, scale)) > 127))
+
+
+def _int8_round(y: np.ndarray, scale: np.ndarray) -> np.ndarray:
+    """``y / scale`` in float32, rounded half to even: int8 codes before
+    the clip."""
+    return np.round(y.astype(np.float32) / scale.astype(np.float32))
 
 
 def quantize(g: jax.Array, error: jax.Array | None = None):

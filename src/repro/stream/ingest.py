@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import threading
 
+from .. import tracing
 from ..cloudsim.collector import DataCollector
 from ..core.config import EngineConfig
 from ..parallel import compression
@@ -165,8 +166,9 @@ class LiveIngestor:
     def poll(self) -> int:
         """Absorb every pending collector tick; return how many."""
         n = self.lag
-        for _ in range(n):
-            self.ingest_tick()
+        with tracing.span("repro.ingest.poll", ticks=n):
+            for _ in range(n):
+                self.ingest_tick()
         return n
 
     def mark_stale(self) -> None:

@@ -27,12 +27,14 @@ and the gather is memoised per version.
 from __future__ import annotations
 
 import functools
+import threading
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..core import scoring
 from ..core.types import CandidateSet
 from ..kernels import stats_update as stats_update_lib
@@ -69,23 +71,21 @@ def _append_step(buf, moments, col, y_old, slot, new_start, length, evict,
 
 @functools.partial(jax.jit, donate_argnums=(0, 1),
                    static_argnames=("precision", "backend", "interpret"))
-def _append_step_q(buf, moments, clips, col, y_old, scale, slot, new_start,
-                   length, evict, *, precision, backend=None, interpret=None):
-    """Quantized-tier tick: encode, donated slot write, fused O(K) update.
+def _append_step_q(buf, moments, col, y_old, scale, slot, new_start, length,
+                   evict, *, precision, backend=None, interpret=None):
+    """Quantized-tier tick: donated slot write + fused O(K) update.
 
-    The incoming float32 column is quantised *inside* the dispatch
-    (``compression.quantize_column``) and only the stored codes touch the
-    ring — the (K, C) buffer stays int8/bf16 end to end.  The moments are
-    updated with the **stored** values (codes via the fused
-    dequantize-and-update ``scale`` path of the stats kernel, bf16 via its
-    exact f32 cast), so the streamed statistics track ``candidate_stats`` of
-    the dequantized window — the tier's ground truth — not of the lossy
-    pre-quantisation column.  ``clips`` accumulates samples that fell
-    outside the int8 clip range (the error-bound contract is void for them,
-    so they are counted, not hidden).  Donation discipline as
-    :func:`_append_step`: ``y_old`` is read in a prior dispatch.
+    ``col`` arrives as the int8 tier stores it: codes made on the host by
+    ``compression.quantize_column``, so they equal a staged window's bit
+    for bit.  On the bf16 tier it is the float32 column, cast here.  Only
+    stored values touch the ring, and the moments are updated with them
+    (codes via the fused dequantize-and-update ``scale`` path of the stats
+    kernel, bf16 via its exact f32 cast), so the streamed statistics track
+    ``candidate_stats`` of the dequantized window, the tier's ground
+    truth.  Donation discipline as :func:`_append_step`: ``y_old`` is read
+    in a prior dispatch.
     """
-    codes, n_clip = compression.quantize_column(col, scale, precision)
+    codes = col if precision == "int8" else col.astype(jnp.bfloat16)
     new_buf = buf.at[:, slot].set(codes)
     y_first = jax.lax.dynamic_index_in_dim(new_buf, new_start, axis=1,
                                            keepdims=False)
@@ -93,7 +93,7 @@ def _append_step_q(buf, moments, clips, col, y_old, scale, slot, new_start,
         moments, codes, y_old, y_first, codes, length, evict,
         scale=scale if precision == "int8" else None,
         backend=backend, interpret=interpret)
-    return new_buf, moments, stats, clips + n_clip
+    return new_buf, moments, stats
 
 
 @dataclass(frozen=True)
@@ -212,7 +212,8 @@ class RollingDeviceArchive:
             t3, self.precision, headroom=headroom)
         quantized = self.precision != "float32"
         self.scale = put(host_scale) if quantized else None
-        self._clips = jax.device_put(jnp.int32(0), device)
+        self._host_scale = host_scale       # ticks are encoded on the host
+        self._clips = 0
         # physical ring: window in slots [0, T), zero-filled tail, cursor at T
         codes = compression.quantize_window(t3, host_scale, self.precision)
         buf = np.zeros((K, capacity), codes.dtype)
@@ -237,6 +238,10 @@ class RollingDeviceArchive:
         self._stats: scoring.CandidateStats | None = None
         self._t3_logical = None
         self.appends = 0
+        # ``append`` publishes the cursor, length, statistics and version
+        # under this lock, and ``snapshot`` / ``score_stats`` read them
+        # under it, so a snapshot's version is always its statistics'.
+        self._lock = threading.Lock()
         #: staleness flag, owned by the feed (``LiveIngestor`` sets it when
         #: its collector stops delivering, clears it on the next successful
         #: tick).  Mutating it does **not** bump :attr:`version` — the
@@ -264,7 +269,7 @@ class RollingDeviceArchive:
         """Samples clipped to the int8 code range since staging (0 on the
         bf16/float32 tiers).  The documented error bound assumes unclipped
         storage; a non-zero count voids it and callers must surface that."""
-        return int(self._clips)
+        return self._clips
 
     @property
     def window_len(self) -> int:
@@ -282,33 +287,45 @@ class RollingDeviceArchive:
     def append(self, column) -> "RollingDeviceArchive":
         """Absorb one collector tick: O(K) work, no (K, T) copy or transfer.
 
-        Writes ``column`` into the ring slot under the cursor (donated
-        in-place update), rank-1-updates the cached Eq. 3 statistics, bumps
-        :attr:`version`, and drops the memoised logical window.  Returns
-        ``self`` for chaining.
+        Encodes ``column`` on the host (int8 codes by
+        ``compression.quantize_column``), writes it into the ring slot under
+        the cursor (donated in-place update), rank-1-updates the cached
+        Eq. 3 statistics, bumps :attr:`version`, and drops the memoised
+        logical window.  The new state is published under the lock
+        :meth:`snapshot` takes.  Returns ``self`` for chaining.
         """
-        step, operands, statics = self._append_dispatch(column)
-        if self.precision == "float32":
-            self._buf, self._moments, stats = step(*operands, **statics)
-        else:
-            self._buf, self._moments, stats, self._clips = step(
-                *operands, **statics)
-        self._pos = (self._pos + 1) % self.capacity
-        self._len = min(self._len + 1, self.capacity)
-        self._stats = stats
-        self._t3_logical = None
-        self.version += 1
-        self.appends += 1
+        with tracing.span("repro.ingest.append") as span:
+            col, n_clip = self._encode(column)
+            span.set_metadata(clipped=n_clip, h2d_bytes=col.nbytes)
+            with self._lock:
+                step, operands, statics = self._append_dispatch(col)
+                self._buf, self._moments, stats = step(*operands, **statics)
+                self._pos = (self._pos + 1) % self.capacity
+                self._len = min(self._len + 1, self.capacity)
+                self._stats = stats
+                self._t3_logical = None
+                self._clips += n_clip
+                self.version += 1
+                self.appends += 1
         return self
 
-    def _append_dispatch(self, column):
-        """``(jitted tick step, operands, static kwargs)`` that
-        :meth:`append` runs to absorb ``column``.  Reads the evicted column
-        (its own dispatch) but changes nothing."""
-        col = jnp.asarray(np.asarray(column, np.float32), jnp.float32)
+    def _encode(self, column):
+        """``(column as uploaded, samples clipped)``: int8 codes made on the
+        host, the float32 column on the other tiers."""
+        col = np.asarray(column, np.float32)
         if col.shape != (len(self.host),):
             raise ValueError(
                 f"column shape {col.shape} != ({len(self.host)},)")
+        if self.precision == "int8":
+            return compression.quantize_column(col, self._host_scale, "int8")
+        return col, 0
+
+    def _append_dispatch(self, col):
+        """``(jitted tick step, operands, static kwargs)`` that
+        :meth:`append` runs to absorb ``col`` (as :meth:`_encode` gives
+        it).  Reads the evicted column (its own dispatch) but changes
+        nothing."""
+        col = jnp.asarray(col)  # spotlint: disable=SPL002 (codes dtype)
         evict = self._len == self.capacity
         new_len = self._len if evict else self._len + 1
         slot = self._pos
@@ -321,16 +338,21 @@ class RollingDeviceArchive:
             return (_append_step,
                     (self._buf, self._moments, col, y_old, *tail), {})
         return (_append_step_q,
-                (self._buf, self._moments, self._clips, col, y_old,
-                 self.scale, *tail), {"precision": self.precision})
+                (self._buf, self._moments, col, y_old, self.scale, *tail),
+                {"precision": self.precision})
 
     def snapshot(self) -> ArchiveSnapshot:
-        """Pin the current version for an in-flight batch (tiled stage)."""
-        return ArchiveSnapshot(
-            key=self.key, version=self.version, host=self.host,
-            prices=self.prices, vcpus=self.vcpus, memory_gb=self.memory_gb,
-            stats=self.score_stats(), window_len=self._len,
-            precision=self.precision, scale=self.scale, stale=self.stale)
+        """Pin the current version for an in-flight batch (tiled stage).
+
+        Read under the lock :meth:`append` publishes under, so the stamped
+        version is always the version of the statistics carried."""
+        with self._lock:
+            return ArchiveSnapshot(
+                key=self.key, version=self.version, host=self.host,
+                prices=self.prices, vcpus=self.vcpus,
+                memory_gb=self.memory_gb, stats=self._current_stats(),
+                window_len=self._len, precision=self.precision,
+                scale=self.scale, stale=self.stale)
 
     # -- engine-facing surface --------------------------------------------
 
@@ -341,6 +363,11 @@ class RollingDeviceArchive:
         out of the rank-1 update kernel — ``candidate_stats`` never runs
         again on this archive.
         """
+        with self._lock:
+            return self._current_stats()
+
+    def _current_stats(self) -> scoring.CandidateStats:
+        """:meth:`score_stats` for a caller that holds the lock."""
         if self._stats is None:     # version 0: derive from the seed moments
             m = self._moments
             y_first = self._decode_col(self._buf[:, self._start])

@@ -7,8 +7,10 @@ The load-bearing contracts:
   quantisation step — the premise every ``repro.core.quantized`` score
   bound is derived from;
 - a staged window (``quantize_window``) and a stream of appended columns
-  (``quantize_column``) land on bit-identical codes, so a rolling ring and
-  a cold re-stage can never disagree about stored content;
+  (``quantize_column``, which encodes a tick on the host through
+  ``quantize_window``) land on bit-identical codes, exact ties of
+  ``y / step`` included, so a rolling ring and a cold re-stage can never
+  disagree about stored content;
 - every scale/output dtype is pinned to float32 explicitly, so enabling
   ``jax_enable_x64`` changes nothing (satellite fix — the gradient path
   used to rely on default promotion).
@@ -90,19 +92,50 @@ def test_column_codes_match_window_codes(window):
     scale = comp.candidate_scales(window, "int8")
     q = comp.quantize_window(window, scale, "int8")
     for t in range(window.shape[1]):
-        codes, clipped = comp.quantize_column(
-            jnp.asarray(window[:, t], jnp.float32), jnp.asarray(scale),
-            "int8")
-        np.testing.assert_array_equal(np.asarray(codes), q[:, t])
-        assert int(clipped) == 0
+        codes, clipped = comp.quantize_column(window[:, t], scale, "int8")
+        assert codes.dtype == np.int8
+        np.testing.assert_array_equal(codes, q[:, t])
+        assert clipped == 0
+
+
+def tie_window():
+    """T3 counts (multiples of 5) whose per-candidate maximum is an even
+    multiple of 5, so the step maximum / 127 puts half the maximum exactly
+    on a tie of ``y / step`` (63.5 in float32), and one tick column: for
+    each maximum, that tie, a count past the clip range, and a count off
+    any tie."""
+    maxima = np.repeat(np.arange(10.0, 51.0, 10.0), 3)
+    window = np.minimum(np.arange(0.0, 51.0, 5.0)[None, :],
+                        maxima[:, None])
+    kind = np.arange(len(maxima)) % 3
+    tick = np.choose(kind, [maxima / 2, maxima + 10, maxima / 2 + 5])
+    return window, tick
+
+
+def test_column_codes_at_exact_ties_and_clips():
+    """A tick column full of exact ties of ``y / step`` and of samples past
+    the clip range encodes as the staged window would, half to even, and
+    every clipped sample is counted."""
+    window, tick = tie_window()
+    scale = comp.candidate_scales(window, "int8")
+    q = np.asarray(tick, np.float32) / scale
+    ties = q - np.floor(q) == np.float32(0.5)
+    over = np.abs(np.round(q)) > 127
+    assert (ties & ~over).sum() == 5
+    codes, clipped = comp.quantize_column(tick, scale, "int8")
+    np.testing.assert_array_equal(
+        codes, comp.quantize_window(tick[:, None], scale, "int8")[:, 0])
+    np.testing.assert_array_equal(codes[ties & ~over], 64)  # 63.5, to even
+    assert clipped == int(over.sum()) == 5
+    np.testing.assert_array_equal(codes[over], 127)
 
 
 def test_column_clipping_is_counted_not_hidden():
     scale = np.full(4, 1.0, np.float32)
-    col = jnp.asarray([10.0, -500.0, 200.0, 127.4])
-    codes, clipped = comp.quantize_column(col, jnp.asarray(scale), "int8")
-    assert int(clipped) == 2
-    np.testing.assert_array_equal(np.asarray(codes), [10, -127, 127, 127])
+    col = np.asarray([10.0, -500.0, 200.0, 127.4])
+    codes, clipped = comp.quantize_column(col, scale, "int8")
+    assert clipped == 2
+    np.testing.assert_array_equal(codes, [10, -127, 127, 127])
 
 
 def test_bf16_effective_step_bounds_cast_error(window):
@@ -151,9 +184,8 @@ def test_quantize_dequantize_pinned_under_x64():
         deq = comp.dequantize_window(
             comp.quantize_window(win, s, "int8"), s, "int8")
         assert deq.dtype == jnp.float32
-        codes, clipped = comp.quantize_column(
-            jnp.asarray(win[:, 0]), jnp.asarray(s), "int8")
-        assert codes.dtype == jnp.int8 and clipped.dtype == jnp.int32
+        codes, clipped = comp.quantize_column(win[:, 0], s, "int8")
+        assert codes.dtype == np.int8 and clipped == 0
     finally:
         jax.config.update("jax_enable_x64", False)
 

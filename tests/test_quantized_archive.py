@@ -8,6 +8,8 @@ recommendation pools must be bit-identical to the float32 tier's whenever
 every Algorithm 1 decision margin exceeds the score bound derived in
 ``repro.core.quantized`` — divergences inside the bound are flagged ties.
 """
+import dataclasses
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -16,12 +18,15 @@ from repro.cloudsim import (Catalog, CollectorConfig, DataCollector,
                             SpotMarket, SPSQueryService)
 from repro.core import (EngineConfig, RecommendationEngine, ResourceRequest,
                         quantized as qz, scoring)
+from repro.core.pool import greedy_pool
 from repro.core.types import RequestBatch
 from repro.parallel import compression as comp
-from repro.serve import ArchiveCache, DeviceArchive, QuantizedDeviceArchive
+from repro.serve import (ArchiveCache, BatchServer, DeviceArchive,
+                         QuantizedDeviceArchive)
 from repro.shard import ShardedArchive, ShardedRollingArchive
 from repro.stream import LiveIngestor, RollingDeviceArchive
 
+from test_compression import tie_window
 from test_serve_batch import synth_candidates
 from test_stream import _collector
 
@@ -143,6 +148,122 @@ def test_rolling_quantized_append_matches_staged_codes():
     got = np.asarray(arch.materialize())
     np.testing.assert_array_equal(
         got, np.asarray(comp.dequantize_window(want, scale, "int8")))
+
+
+def test_rolling_int8_tick_codes_at_exact_ties():
+    """A tick full of exact ties of ``y / step`` and clipped samples is
+    stored as ``quantize_window`` encodes it: the ring receives int8 codes
+    made on the host (K bytes a tick), not a float column to divide on the
+    device, whose divide need not round exactly at a tie."""
+    window, tick = tie_window()
+    K, T = window.shape
+    cands = dataclasses.replace(synth_candidates(8, K=K, T=T), t3=window)
+    arch = RollingDeviceArchive(cands, capacity=T, precision="int8")
+    scale = comp.candidate_scales(window, "int8")
+    np.testing.assert_array_equal(np.asarray(arch.scale), scale)
+    sent, clipped = arch._encode(tick)
+    _, operands, _ = arch._append_dispatch(sent)
+    assert operands[2].dtype == jnp.int8 and sent.nbytes == K
+    slot = arch._pos
+    arch.append(tick)
+    want = comp.quantize_window(tick[:, None], scale, "int8")[:, 0]
+    np.testing.assert_array_equal(np.asarray(arch._buf)[:, slot], want)
+    assert arch.clipped_samples == clipped == 5
+
+
+class WalkCollector:
+    """What ``LiveIngestor`` reads of a collector, over one walk of T3
+    counts: the first ``window`` columns are complete, ``run(n)`` completes
+    ``n`` more."""
+
+    def __init__(self, cands, walk, window):
+        self.cands, self.walk, self.ticks = cands, walk, window
+
+    def run(self, n: int) -> None:
+        self.ticks += n
+
+    def column(self, i: int) -> np.ndarray:
+        return self.walk[:, i]
+
+    def to_candidate_set(self, window: int):
+        return dataclasses.replace(
+            self.cands, t3=self.walk[:, self.ticks - window:self.ticks])
+
+
+def _stats64(window):
+    """Eq. 3 statistics of a window in float64: trapezoid area,
+    least-squares slope, population std."""
+    y = np.asarray(window, np.float64)
+    t = np.arange(y.shape[1]) - (y.shape[1] - 1) / 2.0
+    d = y - y.mean(axis=1, keepdims=True)
+    return (y.sum(axis=1) - 0.5 * (y[:, 0] + y[:, -1]), d @ t / (t @ t),
+            np.sqrt((d * d).mean(axis=1)))
+
+
+def _greedy_reference(req, cands, window):
+    """The pool Algorithm 1 forms from float64 Eq. 2-4 scores over the
+    request's candidates: ``(candidate indices, counts)``."""
+    idx = np.flatnonzero(req.filter_mask(cands))
+    caps = np.asarray(req.capacity_of(cands), np.float64)[idx]
+    avail = scoring.availability_scores_ref(window[idx], req.lam)
+    cost = scoring.cost_scores_ref(cands.prices[idx], caps, req.amount)
+    res = greedy_pool(req.weight * avail + (1 - req.weight) * cost, caps,
+                      req.amount)
+    return idx[res.indices], res.counts
+
+
+def test_ingestor_int8_ring_matches_references_over_60_ticks():
+    """An int8 ring primed by ``LiveIngestor`` and fed 60 ticks of a walk of
+    T3 counts (exact ties and clipped samples among them): its stored codes
+    equal ``quantize_window`` of the walk's window, its statistics float64
+    statistics of the dequantized window, and its served pools Algorithm 1
+    over float64 scores, bit for bit."""
+    rng = np.random.default_rng(16)
+    K, T, N = 96, 24, 60
+    steps = rng.choice([-5.0, 0.0, 0.0, 5.0], (K, T + N))
+    walk = np.clip(rng.integers(0, 11, (K, 1)) * 5.0
+                   + np.cumsum(steps, axis=1), 0.0, 50.0)
+    cands = synth_candidates(16, K=K, T=T)
+    col = WalkCollector(cands, walk, T)
+    ing = LiveIngestor(col, window=T, precision="int8")
+    arch = ing.prime()
+    scale = comp.candidate_scales(walk[:, :T], "int8")
+    server = BatchServer(config=EngineConfig(score_impl="tiled"),
+                         bucket_sizes=(1, 4, 8))
+    reqs = [ResourceRequest(cpus=128.0),
+            ResourceRequest(memory_gb=256.0, weight=0.8),
+            ResourceRequest(cpus=96.0, weight=0.3, lam=0.25),
+            ResourceRequest(cpus=64.0, regions=[str(cands.regions[0])])]
+    ties = 0
+    for tick in range(1, N + 1):
+        col.run(1)
+        assert ing.poll() == 1 and arch.version == tick
+        q = walk[:, T + tick - 1].astype(np.float32) / scale
+        ties += int((q - np.floor(q) == np.float32(0.5)).sum())
+        if tick % 20:
+            continue
+        order = (arch._start + np.arange(T)) % arch.capacity
+        codes = comp.quantize_window(walk[:, tick:tick + T], scale, "int8")
+        np.testing.assert_array_equal(np.asarray(arch._buf)[:, order], codes)
+        stored = np.asarray(comp.dequantize_window(codes, scale, "int8"),
+                            np.float64)
+        area, slope, std = (np.asarray(x, np.float64)
+                            for x in arch.score_stats())
+        want = _stats64(stored)
+        _assert_stats_close((area, slope), want[:2])
+        # the streamed std is the root of a difference of float32 moments
+        # of size ~max(y)**2: compare variances, to a few of their ulps
+        np.testing.assert_allclose(std ** 2, want[2] ** 2, rtol=RTOL,
+                                   atol=1e-6 * 50.0 ** 2)
+        for req, rec in zip(reqs, server.serve(arch.snapshot(), reqs)):
+            members, counts = _greedy_reference(req, cands, stored)
+            assert list(rec.names) == list(cands.names[members])
+            assert list(rec.azs) == list(cands.azs[members])
+            np.testing.assert_array_equal(rec.counts, counts)
+    assert ties > 0
+    clip = np.abs(np.round(walk[:, T:].astype(np.float32)
+                           / scale[:, None])) > 127
+    assert arch.clipped_samples == int(clip.sum()) > 0
 
 
 @pytest.mark.parametrize("precision", TIERS)

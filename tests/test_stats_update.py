@@ -104,14 +104,14 @@ def test_quantized_long_stream_no_drift(backend, ticks, kwargs):
     codes = comp.quantize_window(win, scale, "int8")
     m = su.moments_from_window(codes, scale=scale)
     for _ in range(ticks):
-        col = jnp.asarray(rng.uniform(0.0, 50.0, K), jnp.float32)
-        new, n_clip = comp.quantize_column(col, jnp.asarray(scale), "int8")
+        new, n_clip = comp.quantize_column(rng.uniform(0.0, 50.0, K), scale,
+                                           "int8")
         y_old = codes[:, 0]
-        codes = _slide(codes, np.asarray(new))
+        codes = _slide(codes, new)
         m, stats = su.stats_update(m, new, y_old, codes[:, 0], codes[:, -1],
                                    T, True, scale=scale, backend=backend,
                                    **kwargs)
-    assert int(n_clip) == 0
+    assert n_clip == 0
     deq = np.asarray(comp.dequantize_window(codes, scale, "int8"))
     _assert_stats_close(stats, scoring.candidate_stats(deq))
     # and against exact float64 reductions of the decoded window

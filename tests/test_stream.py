@@ -477,6 +477,49 @@ def test_threaded_admission_resolves_every_ticket_exactly_once(monkeypatch,
     assert q.pending == 0 and not q.running
 
 
+def test_snapshot_carries_the_statistics_of_its_version(racecheck):
+    """One thread appends while another takes snapshots: every snapshot's
+    statistics are those its version published.  Publishing is slowed
+    between the statistics and the version bump, so a snapshot read
+    outside the append's lock would be caught between the two."""
+    import threading
+
+    from repro.analysis.racecheck import instrument_rolling_archive
+
+    class SlowPublish(RollingDeviceArchive):
+        def __setattr__(self, attr, value):
+            super().__setattr__(attr, value)
+            if attr == "_stats" and value is not None:
+                time.sleep(0.002)
+
+    rng = np.random.default_rng(4)
+    arch = SlowPublish(synth_candidates(seed=9, K=32, T=8), name="race")
+    instrument_rolling_archive(racecheck, arch)
+    published = {0: arch.score_stats()}
+    snaps: list = []
+    done = threading.Event()
+
+    def snapshotter():
+        while not done.is_set():
+            snaps.append(arch.snapshot())
+
+    th = threading.Thread(target=snapshotter)
+    th.start()
+    try:
+        for v in range(1, 31):
+            arch.append(rng.uniform(0.0, 50.0, 32))
+            published[v] = arch.score_stats()
+    finally:
+        done.set()
+        th.join(timeout=30.0)
+    assert not th.is_alive()
+    assert len({s.version for s in snaps}) > 10
+    for snap in snaps:
+        assert snap.key == f"race@v{snap.version}"
+        for got, want in zip(snap.stats, published[snap.version]):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_admission_background_worker_smoke():
     """Wall-clock mode: the daemon thread drains on its own."""
     col = _collector()

@@ -1,10 +1,11 @@
-"""Program spans of the served path (``repro.tracing``) and the read-back
-counter of ``ServeStats``.
+"""Program spans of the served path and of ingestion (``repro.tracing``)
+and the read-back counter of ``ServeStats``.
 
 Off, a span is a shared no-op and no ``TraceAnnotation`` is made; on, under
 a profiler session, one drain writes ``repro.admission.drain`` around
-``repro.serve`` around the engine's stages, in order, with their args; the
-byte counter equals the result bytes the shapes give.
+``repro.serve`` around the engine's stages, in order, with their args, and
+one poll writes ``repro.ingest.poll`` around a ``repro.ingest.append`` per
+tick; the byte counter equals the result bytes the shapes give.
 """
 import glob
 
@@ -16,7 +17,8 @@ from jax.profiler import ProfileData
 from repro import tracing
 from repro.core import EngineConfig, RecommendationEngine, ResourceRequest
 from repro.serve import BatchServer, DeviceArchive
-from repro.stream import AdmissionQueue
+from repro.stream import AdmissionQueue, LiveIngestor
+from test_quantized_archive import WalkCollector
 from test_serve_batch import synth_candidates
 
 K, T = 64, 24
@@ -147,6 +149,65 @@ def test_pools_identical_with_tracing_on_and_off(cands):
         assert a.hourly_cost == b.hourly_cost
         np.testing.assert_array_equal(a.combined, b.combined)
         assert a.diagnostics == b.diagnostics
+
+
+def _ingestor(precision="int8", ticks=2):
+    """A primed ``LiveIngestor`` with ``ticks`` collector ticks pending (and
+    one more in its walk): each repeats a primed column, except that the
+    second tick's first two samples lie past the int8 clip range."""
+    rng = np.random.default_rng(3)
+    primed = rng.uniform(0.0, 50.0, (K, T))
+    walk = np.concatenate([primed, primed[:, :ticks + 1]], axis=1)
+    walk[:2, T + 1] = 1e4
+    col = WalkCollector(synth_candidates(seed=5, K=K, T=T), walk, T)
+    ing = LiveIngestor(col, window=T, precision=precision)
+    ing.prime()
+    col.run(ticks)
+    return ing
+
+
+def test_off_ingest_enters_no_trace_annotation(monkeypatch):
+    made = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **args):
+            made.append(name)
+            super().__init__(name, **args)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    ing = _ingestor(ticks=3)
+    assert ing.poll() == 3 and made == []
+    ing.collector.run(1)
+    tracing.enable()
+    try:
+        ing.poll()
+    finally:
+        tracing.disable()
+    assert made == ["repro.ingest.poll", "repro.ingest.append"]
+
+
+@pytest.mark.parametrize("precision,h2d", [("int8", K), ("float32", 4 * K)])
+def test_ingest_poll_spans_its_appends_with_args(traced, tmp_path,
+                                                 precision, h2d):
+    ing = _ingestor(precision)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert ing.poll() == 2
+    finally:
+        jax.profiler.stop_trace()
+    spans = _program_spans(tmp_path)
+    assert [s[0] for s in spans] == ["repro.ingest.poll",
+                                     "repro.ingest.append",
+                                     "repro.ingest.append"]
+    poll, *appends = spans
+    assert poll[4] == {"ticks": 2}
+    for s in appends:
+        assert poll[1] <= s[1] and s[2] <= poll[2]
+    clipped = 2 if precision == "int8" else 0
+    assert [s[4] for s in appends] == [
+        {"clipped": 0, "h2d_bytes": h2d},
+        {"clipped": clipped, "h2d_bytes": h2d}]
+    assert ing.archive.clipped_samples == clipped
 
 
 def _d2h(b):
